@@ -123,22 +123,31 @@ def bench_service_amortization(n: int = N_REQUESTS) -> Dict:
         and all(r.metrics["new_sim_compiles"] == 0 for r in warm_resp))
 
     # -- leg 4: persistent on-disk compile cache (cross-process story) ---
+    import os
     import tempfile
-    with tempfile.TemporaryDirectory() as td:
-        enable_persistent_compilation_cache(td, subkey="bench_service")
-        reset_compilation_cache_stats()
-        _clear_all_caches()
-        SimService(max_batch=n).run(reqs)       # populates the disk cache
-        misses = compilation_cache_stats()["misses"]
-        _clear_all_caches()                     # drop in-process programs
-        t0 = time.perf_counter()
-        SimService(max_batch=n).run(reqs)       # reloads from disk
-        disk_wall = time.perf_counter() - t0
-        disk = compilation_cache_stats()
-        checks["disk_cache_hits"] = disk["hits"] > 0 and misses > 0
-    disable_persistent_compilation_cache()
-    if prior_cache_dir:                          # restore run.py's wiring
-        enable_persistent_compilation_cache(prior_cache_dir)
+    prior_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    try:
+        with tempfile.TemporaryDirectory() as td:
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = td
+            enable_persistent_compilation_cache()
+            reset_compilation_cache_stats()
+            _clear_all_caches()
+            SimService(max_batch=n).run(reqs)   # populates the disk cache
+            misses = compilation_cache_stats()["misses"]
+            _clear_all_caches()                 # drop in-process programs
+            t0 = time.perf_counter()
+            SimService(max_batch=n).run(reqs)   # reloads from disk
+            disk_wall = time.perf_counter() - t0
+            disk = compilation_cache_stats()
+            checks["disk_cache_hits"] = disk["hits"] > 0 and misses > 0
+    finally:
+        disable_persistent_compilation_cache()
+        if prior_env is None:
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+        else:
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = prior_env
+        if prior_cache_dir:                      # restore run.py's wiring
+            enable_persistent_compilation_cache()
 
     print(f"  sequential cold x{n}: {seq_wall:.1f}s "
           f"(mean {np.mean(seq_lat):.2f}s/req)", flush=True)

@@ -11,9 +11,10 @@ frontiers (buffer area vs. saturation throughput) to
 experiments/dse_frontier.json, and the simulation-service amortization
 record to experiments/service_latency.json (uploaded as CI artifacts).
 
-Every run arms JAX's persistent on-disk compilation cache under
-experiments/xla_cache/<config-hash>/ (shared with the sim service and
-repro.dse), and the summary reports its hit/miss/entry counts.
+Every run arms JAX's persistent on-disk compilation cache in
+$JAX_COMPILATION_CACHE_DIR, or experiments/xla_cache/ when that is not
+set (shared with the sim service and repro.dse), and the summary reports
+its hit/miss/entry counts.
 
 Every run also APPENDS a trajectory entry to experiments/BENCH_netsim.json
 — per-benchmark wall seconds with compile time and run time recorded
@@ -183,13 +184,10 @@ def main(argv=None) -> int:
     from repro.compat import set_host_device_count
     set_host_device_count(8)
 
-    # persistent on-disk XLA compilation cache, keyed by the simulator
-    # source hash: repeat bench runs (and the CI bench job, which caches
-    # this directory) deserialize executables instead of re-compiling
+    # persistent on-disk XLA compilation cache: repeat bench runs
+    # deserialize executables instead of re-compiling
     from repro.compat import enable_persistent_compilation_cache
-    from repro.dse.cache import config_hash
-    enable_persistent_compilation_cache(args.out / "xla_cache",
-                                        subkey=config_hash())
+    enable_persistent_compilation_cache()
 
     results: Dict[str, List[Dict]] = {}
     crashed: List[str] = []

@@ -1,8 +1,5 @@
-"""Version compatibility shims.
-
-``shard_map`` moved from ``jax.experimental.shard_map`` to the top-level
-``jax`` namespace in newer releases; the experimental module is slated for
-removal.  Import it from here so the repo runs on both sides of the move:
+"""Device-mesh helpers, the persistent compilation cache, and short
+names for the JAX SPMD primitives the repo uses::
 
     from repro.compat import shard_map
 """
@@ -16,6 +13,7 @@ from typing import Optional
 import jax
 import numpy as np
 from jax import lax
+from jax.experimental.compilation_cache import compilation_cache as _cc
 
 __all__ = ["shard_map", "axis_size", "pcast", "vma_of",
            "make_auto_mesh", "make_auto_device_mesh", "device_mesh_1d",
@@ -23,22 +21,30 @@ __all__ = ["shard_map", "axis_size", "pcast", "vma_of",
            "disable_persistent_compilation_cache",
            "compilation_cache_stats", "reset_compilation_cache_stats"]
 
+shard_map = jax.shard_map
+axis_size = lax.axis_size
+pcast = lax.pcast
+
+
+def vma_of(x):
+    """Varying-manual-axes set of ``x`` inside ``shard_map``."""
+    return tuple(jax.typeof(x).vma)
+
 
 def set_host_device_count(n: int) -> None:
     """Give the process ``n`` CPU devices.  Must run before the first jax
-    backend use.  ``jax_num_cpu_devices`` only exists on jax >= 0.5; older
-    releases need the XLA flag."""
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        flags = os.environ.get("XLA_FLAGS", "")
-        flag = f"--xla_force_host_platform_device_count={n}"
-        if flag not in flags:
-            os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
+    backend use."""
+    jax.config.update("jax_num_cpu_devices", n)
+
 
 # ----------------------------------------------------------------------
 # persistent (on-disk) XLA compilation cache
 # ----------------------------------------------------------------------
+# where the cache lives when JAX_COMPILATION_CACHE_DIR is not set: a fixed
+# path (the directory is part of what a later process must find again)
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / "experiments" \
+    / "xla_cache"
+
 # counters fed by jax.monitoring events; hits/misses are only recorded by
 # jax while a cache dir is configured
 _CACHE_EVENTS: collections.Counter = collections.Counter()
@@ -54,54 +60,35 @@ def _cache_event_listener(event: str, **_kw) -> None:
         _CACHE_EVENTS[event] += 1
 
 
-def _reset_jax_cache_state() -> None:
-    """Force jax to re-resolve the cache directory.  The compilation
-    cache initializes lazily at the first compile and then latches
-    (``_cache_initialized``); without a reset, arming the cache after
-    any jit call in the process is silently a no-op."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # private module moved: fresh-process arming still works
-        pass
+def enable_persistent_compilation_cache() -> Path:
+    """Point JAX's on-disk XLA compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` as given when it is set, otherwise at
+    :data:`DEFAULT_CACHE_DIR` (created if missing), so a later process
+    compiling an identical program deserializes the executable instead of
+    re-running XLA.  The one place the repo arms the cache: JAX's own
+    cache key already covers the program, so the directory carries no
+    subkey.  The entry-size / compile-time floors are dropped so even
+    small programs cache.  Returns the directory; idempotent.
 
-
-def enable_persistent_compilation_cache(cache_dir, *,
-                                        subkey: Optional[str] = None) -> Path:
-    """Point JAX's on-disk XLA compilation cache at ``cache_dir`` (created
-    if missing) so a later process re-compiling an identical program
-    deserializes the executable instead of re-running XLA — the cold-start
-    story for the simulation service and the bench suites.
-
-    ``subkey`` nests the cache one directory deeper (the sim service and
-    :mod:`repro.dse` pass :func:`repro.dse.cache.config_hash`, keying the
-    executables alongside the result cache: editing the simulator sources
-    moves both to a fresh directory together).  The entry-size /
-    compile-time floors are dropped so even the small CI programs cache.
-    Returns the directory actually used; idempotent.
+    The cache initializes lazily at the first compile and then latches;
+    the ``reset_cache`` here makes arming it after earlier jit calls in
+    the process take effect.
     """
     global _CACHE_LISTENER_REGISTERED, _CACHE_DIR
-    path = Path(cache_dir)
-    if subkey:
-        path = path / subkey
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = Path(env) if env else DEFAULT_CACHE_DIR
     path.mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_compilation_cache_dir", str(path))
-    for knob, value in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                        ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, value)
-        except AttributeError:  # knob not present on this jax release
-            pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if not _CACHE_LISTENER_REGISTERED:
-        try:
-            jax.monitoring.register_event_listener(_cache_event_listener)
-            # cache *hits* are reported as duration events, not plain ones
-            jax.monitoring.register_event_duration_secs_listener(
-                lambda event, _secs, **_kw: _cache_event_listener(event))
-            _CACHE_LISTENER_REGISTERED = True
-        except Exception:  # monitoring API moved/absent: stats degrade to 0
-            pass
-    _reset_jax_cache_state()
+        jax.monitoring.register_event_listener(_cache_event_listener)
+        # cache *hits* are reported as duration events, not plain ones
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, _secs, **_kw: _cache_event_listener(event))
+        _CACHE_LISTENER_REGISTERED = True
+    _cc.reset_cache()
     _CACHE_DIR = path
     return path
 
@@ -113,7 +100,7 @@ def disable_persistent_compilation_cache() -> None:
     :func:`enable_persistent_compilation_cache`."""
     global _CACHE_DIR
     jax.config.update("jax_compilation_cache_dir", None)
-    _reset_jax_cache_state()
+    _cc.reset_cache()
     _CACHE_DIR = None
 
 
@@ -138,79 +125,27 @@ def reset_compilation_cache_stats() -> None:
     _CACHE_EVENTS.clear()
 
 
-try:  # jax >= 0.6: public API
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, **_kw):
-        """Old-jax adapter.  ``axis_names`` (the new API's manual subset)
-        maps onto the experimental API's complementary ``auto`` set;
-        replication checking is off because the seed relies on
-        ``lax.pcast`` (absent pre-0.6) to satisfy it."""
-        extra = {}
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                extra["auto"] = auto
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False, **extra)
-
-if hasattr(lax, "axis_size"):
-    axis_size = lax.axis_size
-else:
-    def axis_size(axis_name):
-        """Static size of a mapped axis: ``psum`` of a unit constant folds
-        to a concrete int on pre-0.6 jax."""
-        return lax.psum(1, axis_name)
-
-if hasattr(lax, "pcast"):
-    pcast = lax.pcast
-else:
-    def pcast(x, axis_name, *, to=None):  # noqa: ARG001
-        """No varying-manual-axes type system before jax 0.6 — identity
-        (the adapter above disables replication checking accordingly)."""
-        return x
-
-
-def vma_of(x):
-    """Varying-manual-axes set of ``x`` (``jax.typeof(x).vma`` on jax >= 0.6,
-    empty on older releases, which have no VMA tracking)."""
-    if hasattr(jax, "typeof"):
-        return tuple(getattr(jax.typeof(x), "vma", ()) or ())
-    return ()
-
-
+# ----------------------------------------------------------------------
+# device meshes
+# ----------------------------------------------------------------------
 def make_auto_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode.
-
-    ``axis_types`` / ``jax.sharding.AxisType`` only exist on jax >= 0.5;
-    older releases have no explicit-sharding mode, so plain ``make_mesh``
-    already means Auto there.
-    """
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode."""
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
 
 
 def make_auto_device_mesh(devices, axis_names):
-    """``jax.sharding.Mesh`` over an explicit device array, all axes Auto
-    (same version story as :func:`make_auto_mesh`)."""
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.sharding.Mesh(devices, axis_names, axis_types=axis_types)
-    except (AttributeError, TypeError):
-        return jax.sharding.Mesh(devices, axis_names)
+    """``jax.sharding.Mesh`` over an explicit device array, all axes
+    Auto."""
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.sharding.Mesh(devices, axis_names, axis_types=axis_types)
 
 
 def device_mesh_1d(n: int, axis_name: str = "devices"):
     """A 1-D device mesh over the first ``n`` local devices — the
     fan-out axis :func:`shard_map` batch runners (``repro.dse``) shard
     over.  Raises ``ValueError`` when ``n`` exceeds the devices actually
-    present; callers that want graceful degradation check
-    ``jax.device_count()`` first."""
+    present."""
     devices = jax.devices()
     if not 1 <= n <= len(devices):
         raise ValueError(

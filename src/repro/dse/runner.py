@@ -16,8 +16,8 @@ count by composing the repo's existing scaling machinery:
    ``lax.map`` so peak memory is one chunk of simulator states, not the
    whole bucket.
 4. **Sharding** — with ``devices=N`` the chunked program is wrapped in
-   the :func:`repro.compat.shard_map` adapter over a 1-D device mesh
-   and each device simulates its slice of the bucket.  Requesting more
+   ``jax.shard_map`` over a 1-D device mesh and each device simulates
+   its slice of the bucket.  Requesting more
    devices than the host has degrades gracefully: one warning, then the
    single-device chunked-vmap path (so a spec written for a fleet still
    runs on a laptop).
@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import device_mesh_1d, shard_map
+from repro.compat import device_mesh_1d
 from repro.mesh.traffic import make_traffic
 from repro.netsim_jax.measure import (PhaseStats, SweepKey, batch_stats_fn,
                                       saturation_point)
@@ -127,8 +127,13 @@ def _bucket_jit(key: SweepKey, ndev: int, chunk: int):
         return jax.jit(chunked)
     mesh = device_mesh_1d(ndev, "dse")
     spec = jax.sharding.PartitionSpec("dse")
-    return jax.jit(shard_map(chunked, mesh=mesh,
-                             in_specs=(spec, spec, spec), out_specs=spec))
+    # check_vma=False: each device simulates its own rows with no
+    # collective, and every output is sharded over "dse".  The check would
+    # only reject the scan carry, whose fresh state (init_state's zeros)
+    # is typed device-invariant while the stepped state varies over "dse".
+    return jax.jit(jax.shard_map(chunked, mesh=mesh,
+                                 in_specs=(spec, spec, spec), out_specs=spec,
+                                 check_vma=False))
 
 
 def _pad_rows(n: int, ndev: int, chunk: int) -> Tuple[int, int]:
@@ -209,24 +214,17 @@ def _point_from_record(record: Dict) -> SweepPoint:
 
 def run_sweep(spec: SweepSpec, *, cache_dir=None,
               devices: Optional[int] = None, chunk: int = 16,
-              compile_cache_dir=None,
               progress: Optional[Callable[[str], None]] = None
               ) -> SweepResult:
     """Run (the uncached remainder of) a sweep spec; see the module
     docstring for the pipeline.  ``cache_dir`` may be a directory path
     or a :class:`ResultCache` (None disables caching); ``devices``
     requests the shard_map fan-out width; ``chunk`` bounds how many
-    simulator states are live per device at once.  ``compile_cache_dir``
-    additionally points JAX's persistent (on-disk) compilation cache at
-    that directory, keyed under :func:`~repro.dse.cache.config_hash` —
-    the same cache the simulation service (:mod:`repro.sim_service`)
-    shares, so re-running a sweep in a fresh process deserializes its
-    bucket executables instead of re-compiling them."""
+    simulator states are live per device at once.  A process that armed
+    the persistent compile cache
+    (:func:`repro.compat.enable_persistent_compilation_cache`) reuses
+    its bucket executables across restarts."""
     t0 = time.perf_counter()
-    if compile_cache_dir is not None:
-        from repro.compat import enable_persistent_compilation_cache
-        enable_persistent_compilation_cache(compile_cache_dir,
-                                            subkey=config_hash())
     log = progress if progress is not None else (lambda msg: None)
     cache = cache_dir if isinstance(cache_dir, ResultCache) \
         else ResultCache(cache_dir)

@@ -1,11 +1,9 @@
 """Shared Pallas backend detection for every kernel in this package.
 
-One policy, one place: a Pallas kernel compiles natively only where a
-Mosaic backend exists (TPU); everywhere else — this CPU container, GPU
-hosts without the Triton lowering enabled — the kernels run under
-``interpret=True``, which executes the *same* traced kernel body through
-XLA without the hardware lowering.  Bit-for-bit the same program, minus
-the speed.
+One policy, one place: a Pallas kernel compiles natively where a Mosaic
+backend exists (TPU).  Interpret mode (``interpret=True``, the *same*
+traced kernel body executed through XLA without the hardware lowering)
+is for tests on a CPU: bit-for-bit the same program, minus the speed.
 
 Every kernel entry point takes ``interpret: Optional[bool] = None`` and
 resolves it through :func:`resolve_interpret`, so

@@ -5,10 +5,9 @@ Responsibilities:
 * **Padding** to MXU/block-aligned shapes (head_dim -> multiple of 128,
   sequence -> block multiples, GMM dims -> tile multiples) and un-padding
   the result.  Zero/masked padding is exact for all three kernels.
-* **Backend dispatch**: on TPU the kernels compile natively; everywhere else
-  (this CPU container) they run under ``interpret=True``, which executes the
-  kernel body through XLA — bit-for-bit the same program, minus the
-  hardware.  The detection lives in :mod:`repro.kernels.backend` (shared by
+* **Backend dispatch**: on TPU the kernels compile natively; on a CPU
+  (tests) they run under ``interpret=True``, which executes the kernel body
+  through XLA — bit-for-bit the same program, minus the hardware.  The detection lives in :mod:`repro.kernels.backend` (shared by
   every kernel module, including the router-step kernel).
 * **Autodiff**: Pallas calls have no automatic VJP.  Each op carries a
   ``jax.custom_vjp`` whose backward pass recomputes through the pure-jnp

@@ -42,9 +42,8 @@ program:
   Pallas kernel (``impl="pallas"``, :mod:`repro.kernels.router_step`)
   with a static ``cycles_per_call`` inner loop, so several mesh cycles
   execute per kernel launch — same trace, same bits, amortized dispatch.
-  On hosts without a compiled Pallas backend the kernel runs in
-  interpret mode automatically (:mod:`repro.kernels.backend`), keeping
-  results identical everywhere.
+  On a TPU Mosaic compiles the kernel; CPU tests run it in interpret
+  mode (:mod:`repro.kernels.backend`), with identical results.
 
 The numpy :class:`~repro.core.netsim.MeshSim` remains the oracle: the JAX
 path is validated cycle-for-cycle against it in
@@ -308,6 +307,40 @@ def _iota_last(prefix_shape: Tuple[int, ...], n: int,
     return np.arange(n, dtype=np.int32)
 
 
+def _expand(x: jax.Array, axis: int = -1,
+            kernel_safe: bool = False) -> jax.Array:
+    """``jnp.expand_dims(x, axis)``; inside the kernel a bool mask widens
+    to int32 for the reshape (exact), which Mosaic cannot do on bool
+    vectors."""
+    if kernel_safe and x.dtype == jnp.bool_:
+        return jnp.expand_dims(x.astype(I32), axis) != 0
+    return jnp.expand_dims(x, axis)
+
+
+def _col(x: jax.Array, i: int, kernel_safe: bool = False) -> jax.Array:
+    """``x[..., i]``; inside the kernel a bool column is sliced as int32
+    (Mosaic cannot relayout bool vectors)."""
+    if kernel_safe and x.dtype == jnp.bool_:
+        return x.astype(I32)[..., i] != 0
+    return x[..., i]
+
+
+def _stack_last(xs, kernel_safe: bool = False) -> jax.Array:
+    """``jnp.stack(xs, axis=-1)``; inside the kernel a select chain over
+    an iota (Mosaic cannot concatenate along an unaligned minor axis)."""
+    if not kernel_safe:
+        return jnp.stack(xs, axis=-1)
+    shape = jnp.broadcast_shapes(*(x.shape for x in xs))
+    io = _iota_last(shape, len(xs), True)
+    # bool operands select as int32 (Mosaic cannot select i1 vectors)
+    is_bool = xs[0].dtype == jnp.bool_
+    xs = [jnp.broadcast_to(x, shape).astype(I32)[..., None] for x in xs]
+    out = xs[0]
+    for k in range(1, len(xs)):
+        out = jnp.where(io == k, xs[k], out)
+    return out != 0 if is_bool else out
+
+
 # ----------------------------------------------------------------------
 # FIFO primitives (pure)
 # ----------------------------------------------------------------------
@@ -338,7 +371,7 @@ def _fifo_push(f: Fifo, mask: jax.Array, pkt: jax.Array,
     cap = f.buf.shape[-1]
     tail = (f.head + f.count) % depth
     onehot = (_iota_last(tail.shape, cap, kernel_safe) == tail[..., None]) \
-        & mask[..., None]
+        & _expand(mask, -1, kernel_safe)
     buf = jnp.where(onehot[None], pkt[..., None], f.buf)
     return f._replace(buf=buf, count=f.count + mask.astype(I32))
 
@@ -346,6 +379,51 @@ def _fifo_push(f: Fifo, mask: jax.Array, pkt: jax.Array,
 # ----------------------------------------------------------------------
 # router — one fused pass over the stacked (fwd, rev) network axis
 # ----------------------------------------------------------------------
+def _from_neighbors(a: jax.Array, topo: Topology,
+                    kernel_safe: bool = False):
+    """What each tile receives from its four neighbours' facing ports:
+    ``(w, e, n, s)``, each ``a.shape[:-1]``, where ``w`` is the west
+    neighbour's E column, ``e`` the east neighbour's W, ``n`` the north
+    neighbour's S and ``s`` the south neighbour's N.  ``a`` ends in
+    (ny, nx, ports).  Off the edge of a non-wrapped dimension reads 0
+    (False); a wrapped dimension reads the opposite edge (static
+    slice + concatenate: ``jnp.roll`` does not lower on Mosaic).  Inside
+    the kernel the edge is a concatenated zero block and bools shift as
+    int32, since Mosaic lowers neither pads nor bool relayouts."""
+    is_bool = kernel_safe and a.dtype == jnp.bool_
+    if is_bool:
+        a = a.astype(I32)
+
+    def shift(port, axis, from_lower, wrap):
+        # ``axis`` indexes the port-less result.  The kernel takes the
+        # port column first; the fused path slices the range first, so
+        # that XLA folds both slices into one.
+        col = a[..., port] if kernel_safe else None
+
+        def part(lo, hi):
+            if kernel_safe:
+                return lax.slice_in_dim(col, lo, hi, axis=axis)
+            return lax.slice_in_dim(a, lo, hi, axis=axis - 1)[..., port]
+
+        n = a.shape[axis - 1]
+        rest = part(0, n - 1) if from_lower else part(1, n)
+        if not wrap and not kernel_safe:
+            widths = [(0, 0)] * rest.ndim
+            widths[axis] = (1, 0) if from_lower else (0, 1)
+            return jnp.pad(rest, widths)
+        edge = part(n - 1, n) if from_lower else part(0, 1)
+        if not wrap:
+            edge = jnp.zeros_like(edge)
+        parts = (edge, rest) if from_lower else (rest, edge)
+        return jnp.concatenate(parts, axis=axis)
+
+    out = (shift(E, -1, True, topo.wrap_x),    # from x - 1
+           shift(W, -1, False, topo.wrap_x),   # from x + 1
+           shift(S, -2, True, topo.wrap_y),    # from y - 1
+           shift(N, -2, False, topo.wrap_y))   # from y + 1
+    return tuple(o != 0 for o in out) if is_bool else out
+
+
 def _arbitrate_fused(cfg: SimConfig, net: Fifo, rr: jax.Array, xs, ys,
                      depth: jax.Array, cycle: jax.Array,
                      kernel_safe: bool = False,
@@ -375,26 +453,11 @@ def _arbitrate_fused(cfg: SimConfig, net: Fifo, rr: jax.Array, xs, ys,
     x, y = xs[None, :, :, None], ys[None, :, :, None]
     want = topo.route(dx, dy, x, y, cfg.nx, cfg.ny, xp=jnp).astype(I32)
 
-    # Destination space per output port (start-of-cycle, conservative),
-    # assembled with shifts + one stack; the P column is provisionally
-    # True (the deliver gate is applied in _finalize).  Wrapped dimensions
-    # connect the edges (static slice+concat — jnp.roll does not lower in
-    # the Pallas kernel); non-wrapped dimensions keep the pad form.
+    # Destination space per output port (start-of-cycle, conservative):
+    # each output sees its neighbour's facing input FIFO.  The P column
+    # is provisionally True (the deliver gate is applied in _finalize).
     space = net.count < depth                   # (2, ny, nx, 5)
-    pad = functools.partial(jnp.pad, mode="constant", constant_values=False)
-    z1 = ((0, 0),)
-    if topo.wrap_x:
-        w_sp = jnp.concatenate([space[:, :, -1:, E], space[:, :, :-1, E]], axis=2)
-        e_sp = jnp.concatenate([space[:, :, 1:, W], space[:, :, :1, W]], axis=2)
-    else:
-        w_sp = pad(space[:, :, :-1, E], z1 + ((0, 0), (1, 0)))  # W out -> west nbr's E
-        e_sp = pad(space[:, :, 1:, W], z1 + ((0, 0), (0, 1)))   # E out -> east nbr's W
-    if topo.wrap_y:
-        n_sp = jnp.concatenate([space[:, -1:, :, S], space[:, :-1, :, S]], axis=1)
-        s_sp = jnp.concatenate([space[:, 1:, :, N], space[:, :1, :, N]], axis=1)
-    else:
-        n_sp = pad(space[:, :-1, :, S], z1 + ((1, 0), (0, 0)))  # N out -> north nbr's S
-        s_sp = pad(space[:, 1:, :, N], z1 + ((0, 1), (0, 0)))   # S out -> south nbr's N
+    w_sp, e_sp, n_sp, s_sp = _from_neighbors(space, topo, kernel_safe)
 
     # Multi-chip boundary links accept one flit every boundary_period
     # cycles (the narrower off-chip channel): gate the E output of the
@@ -416,10 +479,10 @@ def _arbitrate_fused(cfg: SimConfig, net: Fifo, rr: jax.Array, xs, ys,
         e_sp = e_sp & (open_now | ~e_gate)
         w_sp = w_sp & (open_now | ~w_gate)
 
-    out_space = jnp.stack([
+    out_space = _stack_last([
         jnp.ones(space.shape[:-1], bool),               # P (gated later)
         w_sp, e_sp, n_sp, s_sp,
-    ], axis=-1)
+    ], kernel_safe)
 
     # Round-robin arbitration, all five output ports of both networks at
     # once: per output port o, the valid requester with minimal
@@ -430,9 +493,9 @@ def _arbitrate_fused(cfg: SimConfig, net: Fifo, rr: jax.Array, xs, ys,
     else:
         io = np.arange(NUM_DIRS, dtype=np.int32)
         io_out, io_in = io[None, None, None, None, :], io[:, None]
-    cand = (valid[..., :, None]                 # (2, ny, nx, in, out)
+    cand = (_expand(valid, -1, kernel_safe)     # (2, ny, nx, in, out)
             & (want[..., :, None] == io_out)
-            & out_space[..., None, :])
+            & _expand(out_space, -2, kernel_safe))
 
     # Ring bubble flow control (see repro.mesh.topology): a packet
     # ENTERING a wrapped-dimension ring needs TWO free slots in the target
@@ -442,17 +505,12 @@ def _arbitrate_fused(cfg: SimConfig, net: Fifo, rr: jax.Array, xs, ys,
     if topo.wrap_x or topo.wrap_y:
         space2 = net.count < depth - 1          # >= 2 free slots
         ones2 = jnp.ones(space2.shape[:-1], bool)
-        if topo.wrap_x:
-            w2 = jnp.concatenate([space2[:, :, -1:, E], space2[:, :, :-1, E]], axis=2)
-            e2 = jnp.concatenate([space2[:, :, 1:, W], space2[:, :, :1, W]], axis=2)
-        else:
+        w2, e2, n2, s2 = _from_neighbors(space2, topo, kernel_safe)
+        if not topo.wrap_x:
             w2 = e2 = ones2
-        if topo.wrap_y:
-            n2 = jnp.concatenate([space2[:, -1:, :, S], space2[:, :-1, :, S]], axis=1)
-            s2 = jnp.concatenate([space2[:, 1:, :, N], space2[:, :1, :, N]], axis=1)
-        else:
+        if not topo.wrap_y:
             n2 = s2 = ones2
-        out_space2 = jnp.stack([ones2, w2, e2, n2, s2], axis=-1)
+        out_space2 = _stack_last([ones2, w2, e2, n2, s2], kernel_safe)
         bubble_out = None                       # which outputs enter rings
         if topo.wrap_x:
             bubble_out = (io_out == E) | (io_out == W)
@@ -461,7 +519,7 @@ def _arbitrate_fused(cfg: SimConfig, net: Fifo, rr: jax.Array, xs, ys,
             bubble_out = b_y if bubble_out is None else (bubble_out | b_y)
         is_cont = io_in == (((io_out - 1) ^ 1) + 1)     # (in, out) broadcast
         need2 = bubble_out & ~is_cont
-        cand = cand & (out_space2[..., None, :] | ~need2)
+        cand = cand & (_expand(out_space2, -2, kernel_safe) | ~need2)
     prio = (io_in - rr[..., None, :]) % NUM_DIRS
     prio = jnp.where(cand, prio, NUM_DIRS + 1)
     best = prio.min(-2)                         # (2, ny, nx, out)
@@ -491,52 +549,35 @@ def _finalize(win: jax.Array, rr: jax.Array, deliver_space: jax.Array,
     arbitration result; returns (rr', pop_mask (ny,nx,in), has (ny,nx,out))
     — bit-identical to arbitrating that network alone with the gate in
     its candidate mask."""
-    win = win.at[..., P].set(jnp.where(deliver_space, win[..., P], -1))
+    if kernel_safe:     # Mosaic lowers no scatter, even at a static index
+        io_out = _iota_last(win.shape[:-1], NUM_DIRS, True)
+        win = jnp.where((io_out == P) & ~_expand(deliver_space, -1, True),
+                        -1, win)
+    else:
+        win = win.at[..., P].set(jnp.where(deliver_space, win[..., P], -1))
     has = win >= 0
     rr = jnp.where(has, (win + 1) % NUM_DIRS, rr)
     widx = jnp.clip(win, 0, NUM_DIRS - 1)
     io_in = lax.broadcasted_iota(I32, (NUM_DIRS, 1), 0) if kernel_safe \
         else np.arange(NUM_DIRS, dtype=np.int32)[:, None]
-    pop = ((io_in == widx[..., None, :]) & has[..., None, :]).any(-1)
+    pop = ((io_in == widx[..., None, :])
+           & _expand(has, -2, kernel_safe)).any(-1)
     return rr, pop, has
 
 
 def _neighbor_push_masks(has: jax.Array, moved_pkt: jax.Array,
                          p_mask: jax.Array, p_pkt: jax.Array,
-                         topo: Topology,
+                         topo: Topology, kernel_safe: bool = False,
                          ) -> Tuple[jax.Array, jax.Array]:
     """Turn per-output winners into per-input push masks for the neighbour
     FIFOs, with the local port-P enqueue (endpoint response or program
     injection) folded into the same single write.  Every destination
     (tile, in_port) has exactly one feeder, so this is conflict-free.
     Wrapped dimensions feed the opposite edge (static slice+concat)."""
-    padm = functools.partial(jnp.pad, mode="constant", constant_values=False)
-    padp = jnp.pad
-    # in-port k of tile t receives the opposite-direction output of the
-    # adjacent tile: W <- west nbr's E, E <- east nbr's W, N <- north nbr's
-    # S, S <- south nbr's N; port P is the local enqueue.
-    if topo.wrap_x:
-        w_in = jnp.concatenate([has[:, -1:, E], has[:, :-1, E]], axis=1)
-        e_in = jnp.concatenate([has[:, 1:, W], has[:, :1, W]], axis=1)
-        w_pk = jnp.concatenate([moved_pkt[:, :, -1:, E], moved_pkt[:, :, :-1, E]], axis=2)
-        e_pk = jnp.concatenate([moved_pkt[:, :, 1:, W], moved_pkt[:, :, :1, W]], axis=2)
-    else:
-        w_in = padm(has[:, :-1, E], ((0, 0), (1, 0)))
-        e_in = padm(has[:, 1:, W], ((0, 0), (0, 1)))
-        w_pk = padp(moved_pkt[:, :, :-1, E], ((0, 0), (0, 0), (1, 0)))
-        e_pk = padp(moved_pkt[:, :, 1:, W], ((0, 0), (0, 0), (0, 1)))
-    if topo.wrap_y:
-        n_in = jnp.concatenate([has[-1:, :, S], has[:-1, :, S]], axis=0)
-        s_in = jnp.concatenate([has[1:, :, N], has[:1, :, N]], axis=0)
-        n_pk = jnp.concatenate([moved_pkt[:, -1:, :, S], moved_pkt[:, :-1, :, S]], axis=1)
-        s_pk = jnp.concatenate([moved_pkt[:, 1:, :, N], moved_pkt[:, :1, :, N]], axis=1)
-    else:
-        n_in = padm(has[:-1, :, S], ((1, 0), (0, 0)))
-        s_in = padm(has[1:, :, N], ((0, 1), (0, 0)))
-        n_pk = padp(moved_pkt[:, :-1, :, S], ((0, 0), (1, 0), (0, 0)))
-        s_pk = padp(moved_pkt[:, 1:, :, N], ((0, 0), (0, 1), (0, 0)))
-    mask_in = jnp.stack([p_mask, w_in, e_in, n_in, s_in], axis=-1)
-    pkt_in = jnp.stack([p_pkt, w_pk, e_pk, n_pk, s_pk], axis=-1)
+    w_in, e_in, n_in, s_in = _from_neighbors(has, topo, kernel_safe)
+    w_pk, e_pk, n_pk, s_pk = _from_neighbors(moved_pkt, topo, kernel_safe)
+    mask_in = _stack_last([p_mask, w_in, e_in, n_in, s_in], kernel_safe)
+    pkt_in = _stack_last([p_pkt, w_pk, e_pk, n_pk, s_pk], kernel_safe)
     return mask_in, pkt_in
 
 
@@ -570,10 +611,12 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     kernel (:mod:`repro.kernels.router_step`): the four traced-index
     scatter/gather ops (the latency-histogram ``.at[].add``, the memory
     read, the program-entry fetch and the ``resp_latency > 1`` slot
-    rotation) are swapped for one-hot select/sum forms — pure int32
-    arithmetic, so any summation order is exact and the two variants are
-    bit-identical.  The default keeps XLA's native scatter/gather, which
-    is faster outside the kernel.
+    rotation) are swapped for one-hot select/sum forms, and bool masks
+    are reshaped and stacked as int32 (:func:`_expand`,
+    :func:`_stack_last`) — Mosaic lowers neither scatters nor bool
+    relayouts.  All of it is exact int32 arithmetic, so the two variants
+    are bit-identical.  The default keeps XLA's native scatter/gather,
+    which is faster outside the kernel.
     """
     ny, nx = cfg.ny, cfg.nx
     xs, ys = _coords(cfg, kernel_safe)
@@ -592,7 +635,7 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     bin_idx = jnp.clip(lat, 0, LAT_BINS - 1)
     if kernel_safe:
         bin_oh = (_iota_last(bin_idx.shape, LAT_BINS, True)
-                  == bin_idx[..., None]) & in_win[..., None]
+                  == bin_idx[..., None]) & _expand(in_win, -1, True)
         lat_hist = st.lat_hist + bin_oh.astype(I32).sum((0, 1))
     else:
         lat_hist = st.lat_hist.at[bin_idx].add(in_win.astype(I32))
@@ -607,7 +650,7 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     rmoved = moved2[:, REV]
     rev_head = (st.net.head[REV] + rpop.astype(I32)) % st.fifo_depth
     rev_count = st.net.count[REV] - rpop.astype(I32)
-    absorbed, rpkt = rhas[..., P], rmoved[..., P]
+    absorbed, rpkt = _col(rhas, P, kernel_safe), rmoved[..., P]
     credits = st.credits + absorbed.astype(I32)
     reg_valid = absorbed
     reg_buf = jnp.where(absorbed[None], rpkt, 0)
@@ -635,7 +678,7 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
             inj = jnp.take(st.resp_valid, slot, axis=0)
             inj_pkt = jnp.take(st.resp_buf, slot, axis=1)
     rmask_in, rpkt_in = _neighbor_push_masks(rhas, rmoved, inj, inj_pkt,
-                                             cfg.topology)
+                                             cfg.topology, kernel_safe)
     rev_tail = (rev_head + rev_count) % st.fifo_depth
     rev_count = rev_count + rmask_in.astype(I32)
     if L == 1:
@@ -666,8 +709,9 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     is_cas = can & (req_op == OP_CAS)
     cas_hit = is_cas & (cur == req[_FI["cmp"]])
     newval = jnp.where(is_store | cas_hit, req[_FI["data"]], cur)
-    mem = jnp.where(addr_oh & can[..., None], newval[..., None], st.mem)
-    ep_in = _fifo_pop(st.ep_in, can[..., None],
+    mem = jnp.where(addr_oh & _expand(can, -1, kernel_safe),
+                    newval[..., None], st.mem)
+    ep_in = _fifo_pop(st.ep_in, _expand(can, -1, kernel_safe),
                       jnp.asarray(cfg.ep_fifo, I32))
     rdata = jnp.where(is_load | is_cas, cur, 0)
     # build the response packet: src<->dst swapped so it routes home
@@ -698,8 +742,8 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     fmoved = moved2[:, FWD]
     fwd_head = (st.net.head[FWD] + fpop.astype(I32)) % st.fifo_depth
     fwd_count = st.net.count[FWD] - fpop.astype(I32)
-    got, fpkt = fhas[..., P], fmoved[..., P]
-    ep_in = _fifo_push(ep_in, got[..., None], fpkt[..., None],
+    got, fpkt = _col(fhas, P, kernel_safe), fmoved[..., P]
+    ep_in = _fifo_push(ep_in, _expand(got, -1, kernel_safe), fpkt[..., None],
                        jnp.asarray(cfg.ep_fifo, I32), kernel_safe)
 
     # ---- master injection from the per-tile program -----------------
@@ -728,7 +772,7 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
         jnp.full((ny, nx), c, I32),
     ])                                                      # (F, ny, nx)
     fmask_in, fpkt_in = _neighbor_push_masks(fhas, fmoved, can_inj, pkt,
-                                             cfg.topology)
+                                             cfg.topology, kernel_safe)
     fwd_tail = (fwd_head + fwd_count) % st.fifo_depth
     fwd_count = fwd_count + fmask_in.astype(I32)
     credits = credits - can_inj.astype(I32)
@@ -740,7 +784,7 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     pkt2 = jnp.stack([fpkt_in, rpkt_in], axis=1)            # (F, 2, ny, nx, 5)
     tail2 = jnp.stack([fwd_tail, rev_tail])
     onehot = (_iota_last(tail2.shape, cap, kernel_safe) == tail2[..., None]) \
-        & mask2[..., None]
+        & _expand(mask2, -1, kernel_safe)
     net = Fifo(buf=jnp.where(onehot[None], pkt2[..., None], st.net.buf),
                head=jnp.stack([fwd_head, rev_head]),
                count=jnp.stack([fwd_count, rev_count]))
@@ -783,9 +827,9 @@ def step(cfg: SimConfig, prog: Program, st: SimState, impl: str = "fused",
 
     * ``"fused"`` — the stacked single-trace XLA step (:func:`_step_core`);
     * ``"pallas"`` — the same transition as one Pallas kernel launch
-      (:mod:`repro.kernels.router_step`; interpret mode on hosts without
-      a compiled Pallas backend).  Bit-identical to ``"fused"`` by
-      construction and by test (``tests/test_router_kernel.py``).
+      (:mod:`repro.kernels.router_step`; compiled on TPU, interpret mode
+      in CPU tests).  Bit-identical to ``"fused"`` by construction and by
+      test (``tests/test_router_kernel.py``).
     """
     _check_impl(impl)
     if impl == "pallas":

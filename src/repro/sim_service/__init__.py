@@ -16,10 +16,10 @@ Entry points:
   ``stream``);
 * :class:`SimServer` — the async server (``submit`` + a ``serve()``
   task; consume ``Ticket.stream()`` / ``Ticket.result()``);
-* ``compile_cache_dir=`` on either arms JAX's persistent on-disk
-  compilation cache (shared with :func:`repro.dse.run_sweep` via
-  :func:`repro.compat.enable_persistent_compilation_cache`), making
-  process-cold starts on known shapes ~0 recompiles.
+* :func:`repro.compat.enable_persistent_compilation_cache` arms JAX's
+  persistent on-disk compilation cache for the process (shared with
+  :func:`repro.dse.run_sweep`), making process-cold starts on known
+  shapes ~0 recompiles.
 """
 from .bucketing import BucketKey, bucket_key, next_pow2  # noqa: F401
 from .metrics import ServiceMetrics  # noqa: F401

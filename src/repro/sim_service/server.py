@@ -27,11 +27,10 @@ Scheduling contract (the amortization story):
   ``Ticket.stream()`` under a running ``serve()`` task, or consume the
   sync generator ``SimService.stream``).
 
-With ``compile_cache_dir`` set, the server also arms JAX's persistent
-on-disk compilation cache (keyed under :func:`repro.dse.cache
-.config_hash`, shared with :func:`repro.dse.run_sweep`), so a *process*
-restart on known shapes deserializes executables instead of re-running
-XLA.
+In a process that armed JAX's persistent on-disk compilation cache
+(:func:`repro.compat.enable_persistent_compilation_cache`, shared with
+:func:`repro.dse.run_sweep`), a *process* restart on known shapes
+deserializes executables instead of re-running XLA.
 """
 from __future__ import annotations
 
@@ -121,8 +120,7 @@ class _Bucket:
 class SimServer:
     """Continuous-batching phased-measurement server (see module doc)."""
 
-    def __init__(self, *, max_batch: int = 8, queue_limit: int = 64,
-                 compile_cache_dir=None):
+    def __init__(self, *, max_batch: int = 8, queue_limit: int = 64):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         # batch widths are pow2-padded, so the cap must be a power of two
@@ -135,11 +133,6 @@ class SimServer:
         self._next_rid = 0
         self._tick_event: Optional[asyncio.Event] = None
         self._stop = False
-        if compile_cache_dir is not None:
-            from repro.compat import enable_persistent_compilation_cache
-            from repro.dse.cache import config_hash
-            enable_persistent_compilation_cache(compile_cache_dir,
-                                                subkey=config_hash())
 
     # -- admission -----------------------------------------------------
     def submit(self, request: Request) -> Ticket:

@@ -4,16 +4,11 @@ We give the test process 8 CPU devices (NOT the dry-run's 512 — that flag is
 set only inside launch/dryrun.py) so shard_map / PGAS tests exercise a real
 2x4 mesh while smoke tests still run comfortably on CPU.
 """
-import os
-
 # Must run before jax initializes its backend; conftest import is early
 # enough as long as no test module imports jax at collection time before us.
 import jax
 
-try:  # set the device count before first backend use
-    jax.config.update("jax_num_cpu_devices", 8)
-except Exception:  # pragma: no cover - older jax fallback
-    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
 

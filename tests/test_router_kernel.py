@@ -284,3 +284,22 @@ def test_telemetry_snapshot_survives_donation(impl):
         np.testing.assert_array_equal(
             np.asarray(getattr(snap, f)), want,
             err_msg=f"telemetry snapshot field {f!r} mutated by a later run")
+
+
+def test_vmem_limit_binds_the_compiled_kernel_only():
+    """Interpret mode (the CPU tests' path) runs a mesh the compiled
+    kernel would refuse for VMEM; only ``interpret=False`` raises."""
+    from repro.kernels.router_step import (VMEM_LIMIT_BYTES, router_step_call,
+                                           vmem_bytes)
+    cfg = MeshConfig(nx=32, ny=32, max_out_credits=8).to_sim()
+    prog = load_program(make_traffic("uniform", 32, 32, 4, seed=0))
+    st = init_state(cfg)
+    assert vmem_bytes(prog, st, 2) > VMEM_LIMIT_BYTES
+    out = jax.eval_shape(lambda p, s: router_step_call(cfg, p, s, 2,
+                                                       interpret=True),
+                         prog, st)
+    assert out[1].shape == (2,)
+    with pytest.raises(ValueError, match=r"32x32"):
+        jax.eval_shape(lambda p, s: router_step_call(cfg, p, s, 2,
+                                                     interpret=False),
+                       prog, st)

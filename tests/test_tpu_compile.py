@@ -1,0 +1,110 @@
+"""The simulator's device programs compile for a TPU v5e.
+
+Nothing here runs: each test lowers a program for a described (not
+attached) ``v5e:2x2`` topology and compiles it with the TPU compiler,
+which refuses what the chip would refuse — an op Mosaic cannot lower, a
+kernel over the VMEM limit, a program over device memory.  Interpret
+mode on the CPU cannot catch any of these.
+
+The topology is described only inside a fixture: the TPU library may be
+loaded by one process at a time, and every test worker imports this
+module.  Each compile runs with the persistent compilation cache off (a
+TPU executable written there cannot be read back without a chip).
+"""
+import os
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.router_step import VMEM_LIMIT_BYTES, router_step_call
+from repro.netsim_jax.measure import SweepKey, batch_stats_fn
+from repro.netsim_jax.sim import (I32, PROG_FIELDS, Program, SimConfig,
+                                  init_state, simulate)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _program(cfg: SimConfig, length: int, batch=()):
+    return Program(
+        buf=jax.ShapeDtypeStruct(batch + (len(PROG_FIELDS), cfg.ny, cfg.nx,
+                                          length), I32),
+        length=jax.ShapeDtypeStruct(batch + (cfg.ny, cfg.nx), I32))
+
+
+def _cfg(n: int, m: int) -> SimConfig:
+    return SimConfig(nx=n, ny=m, max_out_credits=64, router_fifo=4)
+
+
+@pytest.mark.parametrize("nx,ny", [(32, 32), (64, 64)])
+def test_fused_simulate_compiles(one_chip, no_persistent_cache, nx, ny):
+    cfg = _cfg(nx, ny)
+    state = jax.eval_shape(lambda: init_state(cfg))
+    compiled = simulate.lower(cfg, _on(one_chip, _program(cfg, 64)),
+                              _on(one_chip, state), 64).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_batched_bucket_compiles(one_chip, no_persistent_cache):
+    """The vmapped phased-measurement bucket that ``dse.run_sweep`` and
+    ``sim_service`` run: 16x16, a batch of 8."""
+    cfg = _cfg(16, 16)
+    key = SweepKey(cfg, warmup=50, measure=100, drain=100)
+    progs = _on(one_chip, _program(cfg, 64, batch=(8,)))
+    knob = jax.ShapeDtypeStruct((8,), I32, sharding=one_chip)
+    jax.jit(batch_stats_fn(key)).lower(progs, knob, knob).compile()
+
+
+@pytest.mark.parametrize("n", [8, 16])
+def test_router_kernel_compiles(one_chip, no_persistent_cache, n):
+    """The Pallas router kernel, 4 cycles per launch, lowers through
+    Mosaic (not interpret mode) and fits the scoped VMEM limit."""
+    cfg = _cfg(n, n)
+    state = jax.eval_shape(lambda: init_state(cfg))
+    step = jax.jit(lambda p, s: router_step_call(cfg, p, s, 4,
+                                                 interpret=False))
+    compiled = step.lower(_on(one_chip, _program(cfg, 8)),
+                          _on(one_chip, state)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_router_kernel_refuses_mesh_over_vmem():
+    """A mesh whose ungridded state cannot fit VMEM is refused before
+    lowering, naming the mesh and the bytes."""
+    cfg = _cfg(64, 64)
+    state = jax.eval_shape(lambda: init_state(cfg))
+    with pytest.raises(ValueError, match=rf"64x64.*{VMEM_LIMIT_BYTES}"):
+        jax.eval_shape(lambda p, s: router_step_call(cfg, p, s, 1,
+                                                     interpret=False),
+                       _program(cfg, 8), state)
