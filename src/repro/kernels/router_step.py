@@ -191,6 +191,7 @@ def router_step_call(cfg, prog, st, cycles_per_call: int, *,
         # copies every input to its output once up front and then works
         # on the outputs alone, so the aliasing is hazard-free)
         input_output_aliases={i: i for i in range(len(packed_st))},
+        name="router_step",
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,

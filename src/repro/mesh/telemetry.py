@@ -21,6 +21,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
+
 __all__ = ["Telemetry", "TELEMETRY_ARRAY_FIELDS", "PORT_NAMES",
            "render_heatmap"]
 
@@ -107,9 +109,11 @@ class Telemetry:
         exactly those buffers, so a snapshot taken at the facade boundary
         would silently mutate later; an explicit copy makes the record a
         true point-in-time snapshot."""
-        return cls(cycles=int(sim.cycle),
-                   **{f: np.array(getattr(sim, f), dtype=np.int64, copy=True)
-                      for f in TELEMETRY_ARRAY_FIELDS})
+        with tracing.span("mesh.telemetry.of"):
+            return cls(cycles=int(sim.cycle),
+                       **{f: np.array(getattr(sim, f), dtype=np.int64,
+                                      copy=True)
+                          for f in TELEMETRY_ARRAY_FIELDS})
 
     def assert_bit_identical(self, other: "Telemetry") -> None:
         """Assert exact equality of every field (the cross-backend

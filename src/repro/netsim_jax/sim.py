@@ -622,177 +622,193 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     xs, ys = _coords(cfg, kernel_safe)
     c = st.cycle
 
-    # ---- registered response port becomes visible (stats record) ----
-    rv = st.reg_valid
-    completed = st.completed + rv.astype(I32)
-    tag = st.reg_buf[_FI["tag"]]
-    lat = c - tag
-    lat_sum = st.lat_sum + jnp.where(rv, lat, 0)
-    done_now = rv.sum().astype(I32)
-    # latency histogram, gated to the measurement window by the packet's
-    # injection cycle (its tag); scatter-add of 0 elsewhere is a no-op
-    in_win = rv & (tag >= st.measure_start) & (tag < st.measure_stop)
-    bin_idx = jnp.clip(lat, 0, LAT_BINS - 1)
-    if kernel_safe:
-        bin_oh = (_iota_last(bin_idx.shape, LAT_BINS, True)
-                  == bin_idx[..., None]) & _expand(in_win, -1, True)
-        lat_hist = st.lat_hist + bin_oh.astype(I32).sum((0, 1))
-    else:
-        lat_hist = st.lat_hist.at[bin_idx].add(in_win.astype(I32))
-
-    # ---- both networks: ONE fused routing + arbitration pass ----
-    win2, moved2 = _arbitrate_fused(cfg, st.net, st.rr, xs, ys,
-                                    st.fifo_depth, c, kernel_safe)
-
-    # ---- reverse network: P deliveries are ALWAYS absorbed ----
-    rr_rev, rpop, rhas = _finalize(win2[REV], st.rr[REV],
-                                   jnp.ones((ny, nx), bool), kernel_safe)
-    rmoved = moved2[:, REV]
-    rev_head = (st.net.head[REV] + rpop.astype(I32)) % st.fifo_depth
-    rev_count = st.net.count[REV] - rpop.astype(I32)
-    absorbed, rpkt = _col(rhas, P, kernel_safe), rmoved[..., P]
-    credits = st.credits + absorbed.astype(I32)
-    reg_valid = absorbed
-    reg_buf = jnp.where(absorbed[None], rpkt, 0)
-
-    # ---- endpoint: inject pending responses into reverse P FIFO ----
-    # (folded into the same stacked buffer write as the neighbour
-    # enqueues; the neighbour pushes never touch port P, so tails are
-    # independent)
-    L = cfg.resp_latency
-    if L == 1:                    # static fast path: slot is always 0
-        slot = jnp.asarray(0, I32)
-        slot_oh = None
-        inj = st.resp_valid[0]                              # (ny, nx)
-        inj_pkt = st.resp_buf[:, 0]                         # (F, ny, nx)
-    else:
-        slot = (c % L).astype(I32)
+    with jax.named_scope("step/stats"):
+        # ---- registered response port becomes visible (stats record) ----
+        rv = st.reg_valid
+        completed = st.completed + rv.astype(I32)
+        tag = st.reg_buf[_FI["tag"]]
+        lat = c - tag
+        lat_sum = st.lat_sum + jnp.where(rv, lat, 0)
+        done_now = rv.sum().astype(I32)
+        # latency histogram, gated to the measurement window by the
+        # packet's injection cycle (its tag); scatter-add of 0 elsewhere
+        # is a no-op
+        in_win = rv & (tag >= st.measure_start) & (tag < st.measure_stop)
+        bin_idx = jnp.clip(lat, 0, LAT_BINS - 1)
         if kernel_safe:
-            # one-hot over the (static, small) slot axis instead of a
-            # traced-index take: exact select, identical bits
-            slot_oh = lax.broadcasted_iota(I32, (L, 1, 1), 0) == slot
-            inj = (st.resp_valid & slot_oh).any(0)
-            inj_pkt = jnp.where(slot_oh[None], st.resp_buf, 0).sum(1)
+            bin_oh = (_iota_last(bin_idx.shape, LAT_BINS, True)
+                      == bin_idx[..., None]) & _expand(in_win, -1, True)
+            lat_hist = st.lat_hist + bin_oh.astype(I32).sum((0, 1))
         else:
+            lat_hist = st.lat_hist.at[bin_idx].add(in_win.astype(I32))
+
+    with jax.named_scope("step/arbitrate"):
+        # ---- both networks: ONE fused routing + arbitration pass ----
+        win2, moved2 = _arbitrate_fused(cfg, st.net, st.rr, xs, ys,
+                                        st.fifo_depth, c, kernel_safe)
+
+    with jax.named_scope("step/endpoint"):
+        # ---- reverse network: P deliveries are ALWAYS absorbed ----
+        rr_rev, rpop, rhas = _finalize(win2[REV], st.rr[REV],
+                                       jnp.ones((ny, nx), bool),
+                                       kernel_safe)
+        rmoved = moved2[:, REV]
+        rev_head = (st.net.head[REV] + rpop.astype(I32)) % st.fifo_depth
+        rev_count = st.net.count[REV] - rpop.astype(I32)
+        absorbed, rpkt = _col(rhas, P, kernel_safe), rmoved[..., P]
+        credits = st.credits + absorbed.astype(I32)
+        reg_valid = absorbed
+        reg_buf = jnp.where(absorbed[None], rpkt, 0)
+
+        # ---- endpoint: inject pending responses into reverse P FIFO ----
+        # (folded into the same stacked buffer write as the neighbour
+        # enqueues; the neighbour pushes never touch port P, so tails are
+        # independent)
+        L = cfg.resp_latency
+        if L == 1:                    # static fast path: slot is always 0
+            slot = jnp.asarray(0, I32)
             slot_oh = None
-            inj = jnp.take(st.resp_valid, slot, axis=0)
-            inj_pkt = jnp.take(st.resp_buf, slot, axis=1)
-    rmask_in, rpkt_in = _neighbor_push_masks(rhas, rmoved, inj, inj_pkt,
-                                             cfg.topology, kernel_safe)
-    rev_tail = (rev_head + rev_count) % st.fifo_depth
-    rev_count = rev_count + rmask_in.astype(I32)
-    if L == 1:
-        resp_valid = jnp.zeros_like(st.resp_valid)
-    elif kernel_safe:
-        resp_valid = st.resp_valid & ~slot_oh
-    else:
-        resp_valid = st.resp_valid.at[slot].set(False)
-    resp_buf = st.resp_buf
+            inj = st.resp_valid[0]                          # (ny, nx)
+            inj_pkt = st.resp_buf[:, 0]                     # (F, ny, nx)
+        else:
+            slot = (c % L).astype(I32)
+            if kernel_safe:
+                # one-hot over the (static, small) slot axis instead of a
+                # traced-index take: exact select, identical bits
+                slot_oh = lax.broadcasted_iota(I32, (L, 1, 1), 0) == slot
+                inj = (st.resp_valid & slot_oh).any(0)
+                inj_pkt = jnp.where(slot_oh[None], st.resp_buf, 0).sum(1)
+            else:
+                slot_oh = None
+                inj = jnp.take(st.resp_valid, slot, axis=0)
+                inj_pkt = jnp.take(st.resp_buf, slot, axis=1)
+        rmask_in, rpkt_in = _neighbor_push_masks(rhas, rmoved, inj,
+                                                 inj_pkt, cfg.topology,
+                                                 kernel_safe)
+        rev_tail = (rev_head + rev_count) % st.fifo_depth
+        rev_count = rev_count + rmask_in.astype(I32)
+        if L == 1:
+            resp_valid = jnp.zeros_like(st.resp_valid)
+        elif kernel_safe:
+            resp_valid = st.resp_valid & ~slot_oh
+        else:
+            resp_valid = st.resp_valid.at[slot].set(False)
+        resp_buf = st.resp_buf
 
-    # ---- endpoint: service one request/cycle (line rate) ----------
-    resp_inflight = resp_valid.sum(0).astype(I32)
-    rev_space = (rev_count[..., P] + resp_inflight) < st.fifo_depth
-    can = (st.ep_in.count[..., 0] > 0) & rev_space
-    req = _fifo_peek(st.ep_in)[..., 0]                      # (F, ny, nx)
-    req_hdr = req[_FI["hdr"]]
-    req_op = (req_hdr >> OP_SHIFT) & OP_MASK
-    addr = jnp.clip(req[_FI["addr"]], 0, cfg.mem_words - 1)
-    addr_oh = _iota_last(addr.shape, cfg.mem_words, kernel_safe) \
-        == addr[..., None]
-    if kernel_safe:
-        # one-hot read reusing the write mask (exact: int32, one hot bit)
-        cur = jnp.where(addr_oh, st.mem, 0).sum(-1)
-    else:
-        cur = jnp.take_along_axis(st.mem, addr[..., None], axis=-1)[..., 0]
-    is_store = can & (req_op == OP_STORE)
-    is_load = can & (req_op == OP_LOAD)
-    is_cas = can & (req_op == OP_CAS)
-    cas_hit = is_cas & (cur == req[_FI["cmp"]])
-    newval = jnp.where(is_store | cas_hit, req[_FI["data"]], cur)
-    mem = jnp.where(addr_oh & _expand(can, -1, kernel_safe),
-                    newval[..., None], st.mem)
-    ep_in = _fifo_pop(st.ep_in, _expand(can, -1, kernel_safe),
-                      jnp.asarray(cfg.ep_fifo, I32))
-    rdata = jnp.where(is_load | is_cas, cur, 0)
-    # build the response packet: src<->dst swapped so it routes home
-    resp = jnp.stack([
-        swap_for_response(req_hdr, xs, ys),
-        req[_FI["addr"]], rdata, req[_FI["cmp"]], req[_FI["tag"]],
-    ])
-    if L == 1:                    # resp_valid[0] was just cleared above
-        resp_valid = can[None]
-        resp_buf = jnp.where(can[None, None], resp[:, None], resp_buf)
-    elif kernel_safe:
-        # refill the just-cleared slot (so `where(can, True, False)` is
-        # simply `can`) and overwrite its packet lanes where `can`
-        resp_valid = jnp.where(slot_oh, can[None], resp_valid)
-        resp_buf = jnp.where(slot_oh[None] & can[None, None],
-                             resp[:, None], resp_buf)
-    else:
-        wslot = slot              # c % L: inject and refill the same slot
-        resp_valid = resp_valid.at[wslot].set(
-            jnp.where(can, True, jnp.take(resp_valid, wslot, axis=0)))
-        resp_buf = resp_buf.at[:, wslot].set(
-            jnp.where(can[None], resp, jnp.take(resp_buf, wslot, axis=1)))
+        # ---- endpoint: service one request/cycle (line rate) ------
+        resp_inflight = resp_valid.sum(0).astype(I32)
+        rev_space = (rev_count[..., P] + resp_inflight) < st.fifo_depth
+        can = (st.ep_in.count[..., 0] > 0) & rev_space
+        req = _fifo_peek(st.ep_in)[..., 0]                  # (F, ny, nx)
+        req_hdr = req[_FI["hdr"]]
+        req_op = (req_hdr >> OP_SHIFT) & OP_MASK
+        addr = jnp.clip(req[_FI["addr"]], 0, cfg.mem_words - 1)
+        addr_oh = _iota_last(addr.shape, cfg.mem_words, kernel_safe) \
+            == addr[..., None]
+        if kernel_safe:
+            # one-hot read reusing the write mask (exact: int32, one hot
+            # bit)
+            cur = jnp.where(addr_oh, st.mem, 0).sum(-1)
+        else:
+            cur = jnp.take_along_axis(st.mem, addr[..., None],
+                                      axis=-1)[..., 0]
+        is_store = can & (req_op == OP_STORE)
+        is_load = can & (req_op == OP_LOAD)
+        is_cas = can & (req_op == OP_CAS)
+        cas_hit = is_cas & (cur == req[_FI["cmp"]])
+        newval = jnp.where(is_store | cas_hit, req[_FI["data"]], cur)
+        mem = jnp.where(addr_oh & _expand(can, -1, kernel_safe),
+                        newval[..., None], st.mem)
+        ep_in = _fifo_pop(st.ep_in, _expand(can, -1, kernel_safe),
+                          jnp.asarray(cfg.ep_fifo, I32))
+        rdata = jnp.where(is_load | is_cas, cur, 0)
+        # build the response packet: src<->dst swapped so it routes home
+        resp = jnp.stack([
+            swap_for_response(req_hdr, xs, ys),
+            req[_FI["addr"]], rdata, req[_FI["cmp"]], req[_FI["tag"]],
+        ])
+        if L == 1:                    # resp_valid[0] was just cleared above
+            resp_valid = can[None]
+            resp_buf = jnp.where(can[None, None], resp[:, None], resp_buf)
+        elif kernel_safe:
+            # refill the just-cleared slot (so `where(can, True, False)`
+            # is simply `can`) and overwrite its packet lanes where `can`
+            resp_valid = jnp.where(slot_oh, can[None], resp_valid)
+            resp_buf = jnp.where(slot_oh[None] & can[None, None],
+                                 resp[:, None], resp_buf)
+        else:
+            wslot = slot          # c % L: inject and refill the same slot
+            resp_valid = resp_valid.at[wslot].set(
+                jnp.where(can, True, jnp.take(resp_valid, wslot, axis=0)))
+            resp_buf = resp_buf.at[:, wslot].set(
+                jnp.where(can[None], resp,
+                          jnp.take(resp_buf, wslot, axis=1)))
 
-    # ---- forward network: P deliveries go to endpoint FIFO ----
-    rr_fwd, fpop, fhas = _finalize(win2[FWD], st.rr[FWD],
-                                   ep_in.count[..., 0] < cfg.ep_fifo,
-                                   kernel_safe)
-    fmoved = moved2[:, FWD]
-    fwd_head = (st.net.head[FWD] + fpop.astype(I32)) % st.fifo_depth
-    fwd_count = st.net.count[FWD] - fpop.astype(I32)
-    got, fpkt = _col(fhas, P, kernel_safe), fmoved[..., P]
-    ep_in = _fifo_push(ep_in, _expand(got, -1, kernel_safe), fpkt[..., None],
-                       jnp.asarray(cfg.ep_fifo, I32), kernel_safe)
+    with jax.named_scope("step/inject"):
+        # ---- forward network: P deliveries go to endpoint FIFO ----
+        rr_fwd, fpop, fhas = _finalize(win2[FWD], st.rr[FWD],
+                                       ep_in.count[..., 0] < cfg.ep_fifo,
+                                       kernel_safe)
+        fmoved = moved2[:, FWD]
+        fwd_head = (st.net.head[FWD] + fpop.astype(I32)) % st.fifo_depth
+        fwd_count = st.net.count[FWD] - fpop.astype(I32)
+        got, fpkt = _col(fhas, P, kernel_safe), fmoved[..., P]
+        ep_in = _fifo_push(ep_in, _expand(got, -1, kernel_safe),
+                           fpkt[..., None], jnp.asarray(cfg.ep_fifo, I32),
+                           kernel_safe)
 
-    # ---- master injection from the per-tile program -----------------
-    # The injection enqueue targets port P of the post-pop forward FIFOs
-    # (neighbour pushes never touch port P), so it folds into the same
-    # stacked buffer write as the neighbour enqueues.
-    pending = st.prog_ptr < prog.length
-    out_of_credit = st.out_of_credit_cycles + \
-        (pending & (credits <= 0)).astype(I32)
-    can_inj = pending & (credits > 0)
-    Lp = prog.buf.shape[-1]
-    pidx = jnp.clip(st.prog_ptr, 0, max(Lp - 1, 0))
-    if kernel_safe:
-        lp_oh = _iota_last(pidx.shape, Lp, True) == pidx[..., None]
-        entry = jnp.where(lp_oh[None], prog.buf, 0).sum(-1)  # (|PROG|, ny, nx)
-    else:
-        entry = jnp.take_along_axis(
-            prog.buf, jnp.broadcast_to(pidx[None, ..., None],
-                                       (len(PROG_FIELDS), ny, nx, 1)),
-            axis=-1)[..., 0]                                # (|PROG|, ny, nx)
-    can_inj = can_inj & (entry[_PI["not_before"]] <= c)
-    can_inj = can_inj & (fwd_count[..., P] < st.fifo_depth)
-    pkt = jnp.stack([
-        with_src(entry[_PI["hdr"]], xs, ys),
-        entry[_PI["addr"]], entry[_PI["data"]], entry[_PI["cmp"]],
-        jnp.full((ny, nx), c, I32),
-    ])                                                      # (F, ny, nx)
-    fmask_in, fpkt_in = _neighbor_push_masks(fhas, fmoved, can_inj, pkt,
-                                             cfg.topology, kernel_safe)
-    fwd_tail = (fwd_head + fwd_count) % st.fifo_depth
-    fwd_count = fwd_count + fmask_in.astype(I32)
-    credits = credits - can_inj.astype(I32)
-    prog_ptr = st.prog_ptr + can_inj.astype(I32)
+        # ---- master injection from the per-tile program -------------
+        # The injection enqueue targets port P of the post-pop forward
+        # FIFOs (neighbour pushes never touch port P), so it folds into
+        # the same stacked buffer write as the neighbour enqueues.
+        pending = st.prog_ptr < prog.length
+        out_of_credit = st.out_of_credit_cycles + \
+            (pending & (credits <= 0)).astype(I32)
+        can_inj = pending & (credits > 0)
+        Lp = prog.buf.shape[-1]
+        pidx = jnp.clip(st.prog_ptr, 0, max(Lp - 1, 0))
+        if kernel_safe:
+            lp_oh = _iota_last(pidx.shape, Lp, True) == pidx[..., None]
+            # (|PROG|, ny, nx)
+            entry = jnp.where(lp_oh[None], prog.buf, 0).sum(-1)
+        else:
+            entry = jnp.take_along_axis(
+                prog.buf, jnp.broadcast_to(pidx[None, ..., None],
+                                           (len(PROG_FIELDS), ny, nx, 1)),
+                axis=-1)[..., 0]                        # (|PROG|, ny, nx)
+        can_inj = can_inj & (entry[_PI["not_before"]] <= c)
+        can_inj = can_inj & (fwd_count[..., P] < st.fifo_depth)
+        pkt = jnp.stack([
+            with_src(entry[_PI["hdr"]], xs, ys),
+            entry[_PI["addr"]], entry[_PI["data"]], entry[_PI["cmp"]],
+            jnp.full((ny, nx), c, I32),
+        ])                                                  # (F, ny, nx)
+        fmask_in, fpkt_in = _neighbor_push_masks(fhas, fmoved, can_inj,
+                                                 pkt, cfg.topology,
+                                                 kernel_safe)
+        fwd_tail = (fwd_head + fwd_count) % st.fifo_depth
+        fwd_count = fwd_count + fmask_in.astype(I32)
+        credits = credits - can_inj.astype(I32)
+        prog_ptr = st.prog_ptr + can_inj.astype(I32)
 
-    # ---- deferred stacked buffer write: both networks at once ----
-    cap = st.net.buf.shape[-1]
-    mask2 = jnp.stack([fmask_in, rmask_in])                 # (2, ny, nx, 5)
-    pkt2 = jnp.stack([fpkt_in, rpkt_in], axis=1)            # (F, 2, ny, nx, 5)
-    tail2 = jnp.stack([fwd_tail, rev_tail])
-    onehot = (_iota_last(tail2.shape, cap, kernel_safe) == tail2[..., None]) \
-        & _expand(mask2, -1, kernel_safe)
-    net = Fifo(buf=jnp.where(onehot[None], pkt2[..., None], st.net.buf),
-               head=jnp.stack([fwd_head, rev_head]),
-               count=jnp.stack([fwd_count, rev_count]))
+    with jax.named_scope("step/commit"):
+        # ---- deferred stacked buffer write: both networks at once ----
+        cap = st.net.buf.shape[-1]
+        mask2 = jnp.stack([fmask_in, rmask_in])             # (2, ny, nx, 5)
+        pkt2 = jnp.stack([fpkt_in, rpkt_in], axis=1)     # (F, 2, ny, nx, 5)
+        tail2 = jnp.stack([fwd_tail, rev_tail])
+        onehot = (_iota_last(tail2.shape, cap, kernel_safe)
+                  == tail2[..., None]) & _expand(mask2, -1, kernel_safe)
+        net = Fifo(buf=jnp.where(onehot[None], pkt2[..., None],
+                                 st.net.buf),
+                   head=jnp.stack([fwd_head, rev_head]),
+                   count=jnp.stack([fwd_count, rev_count]))
 
-    # ---- telemetry: link counts + occupancy high-water marks ----------
-    link_util = st.link_util + jnp.stack([fhas, rhas]).astype(I32)
-    fifo_hwm = jnp.maximum(st.fifo_hwm, net.count)
-    ep_hwm = jnp.maximum(st.ep_hwm, ep_in.count[..., 0])
+    with jax.named_scope("step/telemetry"):
+        # ---- telemetry: link counts + occupancy high-water marks ------
+        link_util = st.link_util + jnp.stack([fhas, rhas]).astype(I32)
+        fifo_hwm = jnp.maximum(st.fifo_hwm, net.count)
+        ep_hwm = jnp.maximum(st.ep_hwm, ep_in.count[..., 0])
 
     st = SimState(net=net, ep_in=ep_in,
                   resp_valid=resp_valid, resp_buf=resp_buf, mem=mem,
@@ -896,7 +912,8 @@ def _drain_loop(cfg: SimConfig, prog: Program, state: SimState,
     K = check_every
     blocks = -(-max_cycles // K)
     c0 = state.cycle
-    d0 = jnp.where(drained(state, prog), c0, -1)
+    with jax.named_scope("drain/fence"):
+        d0 = jnp.where(drained(state, prog), c0, -1)
     trace0 = jnp.zeros((blocks * K if trace else 1,), I32)
 
     def cond(carry):
@@ -922,12 +939,14 @@ def _drain_loop(cfg: SimConfig, prog: Program, state: SimState,
             done_vec = jnp.concatenate(dones)        # (K,)
             drain_vec = jnp.concatenate(drains) > 0  # (K,) post-cycle fence
             if trace:
-                tr = lax.dynamic_update_slice(tr, done_vec, (i * K,))
+                with jax.named_scope("drain/trace"):
+                    tr = lax.dynamic_update_slice(tr, done_vec, (i * K,))
             # exact fence cycle: first in-block cycle whose post-step
             # fence held (same recording point as the fused inner scan)
-            first = jnp.argmax(drain_vec).astype(I32)
-            dcyc = jnp.where((dcyc < 0) & drain_vec.any(),
-                             c_start + first + 1, dcyc)
+            with jax.named_scope("drain/fence"):
+                first = jnp.argmax(drain_vec).astype(I32)
+                dcyc = jnp.where((dcyc < 0) & drain_vec.any(),
+                                 c_start + first + 1, dcyc)
             return st, tr, i + 1, dcyc
     else:
         def body(carry):
@@ -937,8 +956,11 @@ def _drain_loop(cfg: SimConfig, prog: Program, state: SimState,
                 st2, tr2, d2 = c2
                 st3, done = _step_core(cfg, prog, st2)
                 if trace:
-                    tr2 = tr2.at[i * K + j].set(done)
-                d2 = jnp.where((d2 < 0) & drained(st3, prog), st3.cycle, d2)
+                    with jax.named_scope("drain/trace"):
+                        tr2 = tr2.at[i * K + j].set(done)
+                with jax.named_scope("drain/fence"):
+                    d2 = jnp.where((d2 < 0) & drained(st3, prog),
+                                   st3.cycle, d2)
                 return (st3, tr2, d2), None
 
             (st, tr, dcyc), _ = lax.scan(inner, (st, tr, dcyc),

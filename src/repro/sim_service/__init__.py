@@ -27,11 +27,10 @@ from .request import (LaneSpec, ServiceOverloaded, SimRequest,  # noqa: F401
                       SimResponse, SweepRequest, SweepResponse)
 from .server import (SimServer, SimService, TelemetryChunk,  # noqa: F401
                      Ticket)
-from .streaming import (BatchRunner, clear_service_cache,  # noqa: F401
-                        executed_shapes)
+from .streaming import BatchRunner, clear_service_cache  # noqa: F401
 
 __all__ = ["SimRequest", "SweepRequest", "SimResponse", "SweepResponse",
            "LaneSpec", "ServiceOverloaded", "BucketKey", "bucket_key",
            "next_pow2", "ServiceMetrics", "SimServer", "SimService",
            "TelemetryChunk", "Ticket", "BatchRunner",
-           "clear_service_cache", "executed_shapes"]
+           "clear_service_cache"]

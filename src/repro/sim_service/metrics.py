@@ -4,18 +4,13 @@ One :class:`ServiceMetrics` per server instance.  Compile counts come
 from the execution engine's honest executed-shape registry
 (:mod:`repro.sim_service.streaming`) — ``sim_compiles`` is the headline
 (simulator-block executables), ``aux_compiles`` the tiny state-init and
-stats-reduce programs.  ``snapshot()`` folds in the persistent on-disk
-compilation-cache counters from :func:`repro.compat
-.compilation_cache_stats`, so a bench run can show both layers: 0
-in-process compiles on a warm service, and disk hits instead of XLA
-compiles on a warm *process*.
+stats-reduce programs.  The persistent on-disk cache keeps its own
+counters (:func:`repro.compat.compilation_cache_stats`); where the time
+of a tick goes is in the host spans of :mod:`repro.tracing`.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
-
-from repro.compat import compilation_cache_stats
 
 __all__ = ["ServiceMetrics"]
 
@@ -29,12 +24,6 @@ class ServiceMetrics:
     ticks: int = 0            # scheduler ticks executed
     batches: int = 0          # batch runners formed
     blocks: int = 0           # vmapped fence-block calls executed
-    chunks: int = 0           # telemetry chunks streamed
     sim_compiles: int = 0     # fresh simulator-block executables
     aux_compiles: int = 0     # fresh init/reduce executables
     peak_pending: int = 0     # max lanes waiting in the bounded queue
-
-    def snapshot(self) -> Dict[str, object]:
-        out = dataclasses.asdict(self)
-        out["compilation_cache"] = compilation_cache_stats()
-        return out

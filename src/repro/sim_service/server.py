@@ -41,6 +41,7 @@ import time
 from typing import (AsyncIterator, Deque, Dict, List, NamedTuple, Optional,
                     Sequence, Tuple, Union)
 
+from repro import tracing
 from repro.netsim_jax.measure import PhaseStats, StreamChunk
 
 from .bucketing import BucketKey, bucket_key, next_pow2
@@ -137,28 +138,31 @@ class SimServer:
     # -- admission -----------------------------------------------------
     def submit(self, request: Request) -> Ticket:
         """Queue a request; raises :class:`ServiceOverloaded` when the
-        bounded queue cannot take its lanes."""
-        lanes = request.lanes()
-        if self._pending + len(lanes) > self.queue_limit:
-            self.metrics.rejected += 1
-            raise ServiceOverloaded(
-                f"queue holds {self._pending}/{self.queue_limit} lanes; "
-                f"request needs {len(lanes)} more — retry after ticks "
-                f"drain the backlog")
+        bounded queue cannot take its lanes.  Every call takes a fresh
+        request id, a refused one too, so each ``sim_service.submit``
+        span names one call."""
         rid = self._next_rid
         self._next_rid += 1
-        ticket = Ticket(self, rid, request, len(lanes))
-        key = request.sweep_key()
-        for idx, lane in enumerate(lanes):
-            bkey = bucket_key(key, lane.program, request.check_every)
-            self._buckets.setdefault(bkey, _Bucket()).waiting.append(
-                _Waiter(ticket, idx, lane))
-        self._pending += len(lanes)
-        self.metrics.submitted += 1
-        self.metrics.lanes += len(lanes)
-        self.metrics.peak_pending = max(self.metrics.peak_pending,
-                                        self._pending)
-        return ticket
+        with tracing.span("sim_service.submit", rid=rid):
+            lanes = request.lanes()
+            if self._pending + len(lanes) > self.queue_limit:
+                self.metrics.rejected += 1
+                raise ServiceOverloaded(
+                    f"queue holds {self._pending}/{self.queue_limit} lanes; "
+                    f"request needs {len(lanes)} more — retry after ticks "
+                    f"drain the backlog")
+            ticket = Ticket(self, rid, request, len(lanes))
+            key = request.sweep_key()
+            for idx, lane in enumerate(lanes):
+                bkey = bucket_key(key, lane.program, request.check_every)
+                self._buckets.setdefault(bkey, _Bucket()).waiting.append(
+                    _Waiter(ticket, idx, lane))
+            self._pending += len(lanes)
+            self.metrics.submitted += 1
+            self.metrics.lanes += len(lanes)
+            self.metrics.peak_pending = max(self.metrics.peak_pending,
+                                            self._pending)
+            return ticket
 
     @property
     def pending_lanes(self) -> int:
@@ -174,68 +178,81 @@ class SimServer:
         advance every in-flight batch by one fence block (one vmapped
         call per bucket).  Returns True when any work ran."""
         did = False
-        for bkey, b in list(self._buckets.items()):
-            if b.inflight is None and b.waiting:
-                take = [b.waiting.popleft()
-                        for _ in range(min(len(b.waiting), self.max_batch))]
-                self._pending -= len(take)
-                b.members = take
-                b.inflight = BatchRunner(bkey, [w.spec for w in take],
-                                         next_pow2(len(take)))
-                now = time.perf_counter()
-                for w in take:
-                    if w.ticket.started_at is None:
-                        w.ticket.started_at = now
-                self.metrics.batches += 1
-            if b.inflight is not None:
-                for lane_i, chunk in b.inflight.advance():
-                    w = b.members[lane_i]
-                    w.ticket.chunks.append(TelemetryChunk(
-                        w.ticket.rid, w.lane_idx, w.spec.label, chunk))
-                    self.metrics.chunks += 1
-                self.metrics.blocks += 1
-                did = True
-                if b.inflight.done:
-                    self._finish(bkey, b)
-            if b.inflight is None and not b.waiting:
-                del self._buckets[bkey]
-        self.metrics.ticks += 1
-        self._notify()
+        with tracing.span("sim_service.tick"):
+            for bkey, b in list(self._buckets.items()):
+                if b.inflight is None and b.waiting:
+                    self._form(bkey, b)
+                if b.inflight is not None:
+                    for lane_i, chunk in b.inflight.advance():
+                        w = b.members[lane_i]
+                        w.ticket.chunks.append(TelemetryChunk(
+                            w.ticket.rid, w.lane_idx, w.spec.label, chunk))
+                    self.metrics.blocks += 1
+                    did = True
+                    if b.inflight.done:
+                        self._finish(bkey, b)
+                if b.inflight is None and not b.waiting:
+                    del self._buckets[bkey]
+            self.metrics.ticks += 1
+            self._notify()
         return did
+
+    def _form(self, bkey: BucketKey, b: _Bucket) -> None:
+        """A batch of every lane waiting in the bucket, up to
+        ``max_batch``; its serial number is the count of batches the
+        server formed before it."""
+        n = min(len(b.waiting), self.max_batch)
+        batch, width = self.metrics.batches, next_pow2(n)
+        with tracing.span("sim_service.batch.form", batch=batch,
+                          width=width):
+            take = [b.waiting.popleft() for _ in range(n)]
+            self._pending -= n
+            b.members = take
+            b.inflight = BatchRunner(bkey, [w.spec for w in take], width,
+                                     batch=batch)
+            now = time.perf_counter()
+            for w in take:
+                if w.ticket.started_at is None:
+                    w.ticket.started_at = now
+            self.metrics.batches += 1
 
     def _finish(self, bkey: BucketKey, b: _Bucket) -> None:
         runner = b.inflight
         assert runner is not None
-        stats = runner.finalize()
-        self.metrics.sim_compiles += runner.sim_compiles
-        self.metrics.aux_compiles += runner.aux_compiles
-        now = time.perf_counter()
-        for w, st in zip(b.members, stats):
-            t = w.ticket
-            t.stats[w.lane_idx] = st
-            t._runner_meta = {
-                "bucket": f"{bkey.key.cfg.topology.spec}-"
-                          f"{bkey.key.cfg.nx}x{bkey.key.cfg.ny}"
-                          f"/L{bkey.prog_len}/ce{bkey.check_every}",
-                "batch_width": runner.width,
-                "batch_lanes": len(runner.lanes),
-                "blocks": len(runner.schedule),
-                "new_sim_compiles": runner.sim_compiles,
-                "new_aux_compiles": runner.aux_compiles,
-            }
-            if all(s is not None for s in t.stats):
-                meta = dict(t._runner_meta)
-                meta.update(
-                    queue_wait_s=round((t.started_at or now)
-                                       - t.submitted_at, 6),
-                    service_s=round(now - (t.started_at or now), 6),
-                    total_s=round(now - t.submitted_at, 6),
-                    chunks=len(t.chunks))
-                t.response = t.request.build_response(t.rid, t.stats, meta)
-                t.done = True
-                self.metrics.completed += 1
-        b.inflight = None
-        b.members = []
+        with tracing.span("sim_service.batch.finalize",
+                          batch=runner.batch):
+            stats = runner.finalize()
+            self.metrics.sim_compiles += runner.sim_compiles
+            self.metrics.aux_compiles += runner.aux_compiles
+            now = time.perf_counter()
+            for w, st in zip(b.members, stats):
+                t = w.ticket
+                t.stats[w.lane_idx] = st
+                t._runner_meta = {
+                    "bucket": f"{bkey.key.cfg.topology.spec}-"
+                              f"{bkey.key.cfg.nx}x{bkey.key.cfg.ny}"
+                              f"/L{bkey.prog_len}/ce{bkey.check_every}",
+                    "batch": runner.batch,
+                    "batch_width": runner.width,
+                    "batch_lanes": len(runner.lanes),
+                    "blocks": len(runner.schedule),
+                    "new_sim_compiles": runner.sim_compiles,
+                    "new_aux_compiles": runner.aux_compiles,
+                }
+                if all(s is not None for s in t.stats):
+                    meta = dict(t._runner_meta)
+                    meta.update(
+                        queue_wait_s=round((t.started_at or now)
+                                           - t.submitted_at, 6),
+                        service_s=round(now - (t.started_at or now), 6),
+                        total_s=round(now - t.submitted_at, 6),
+                        chunks=len(t.chunks))
+                    t.response = t.request.build_response(t.rid, t.stats,
+                                                          meta)
+                    t.done = True
+                    self.metrics.completed += 1
+            b.inflight = None
+            b.members = []
 
     def run_until_idle(self, max_ticks: int = 1_000_000) -> int:
         """Drive ticks synchronously until every request finished."""

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.netsim_jax.measure import (PhaseStats, StreamChunk, SweepKey,
                                       phase_schedule, reduce_window_stats)
 from repro.netsim_jax.sim import init_state, simulate
@@ -39,7 +40,7 @@ from repro.netsim_jax.sim import init_state, simulate
 from .bucketing import BucketKey, stack_lanes
 from .request import LaneSpec
 
-__all__ = ["BatchRunner", "clear_service_cache", "executed_shapes"]
+__all__ = ["BatchRunner", "clear_service_cache"]
 
 # shapes (block/init/reduce executables) already executed by this
 # process — the line between a fresh XLA compilation and a jit-cache hit
@@ -95,11 +96,6 @@ def _note(shape_id) -> bool:
     return fresh
 
 
-def executed_shapes() -> int:
-    """How many distinct executable shapes this process has run."""
-    return len(_EXECUTED)
-
-
 def clear_service_cache() -> None:
     """Drop the service's jitted programs AND the executed-shape
     registry — the cold-start reset the benchmarks use (pair with
@@ -115,11 +111,12 @@ class BatchRunner:
     stream per-lane chunk deltas, reduce to per-lane PhaseStats at the
     end.  ``width`` is the padded (pow2) lane count actually executed;
     ``lanes`` the real requests (padding replicates lane 0 and is
-    dropped)."""
+    dropped); ``batch`` the serial number its spans carry."""
 
     def __init__(self, bkey: BucketKey, lanes: Sequence[LaneSpec],
-                 width: int):
+                 width: int, *, batch: int = 0):
         self.bkey = bkey
+        self.batch = batch
         self.lanes = list(lanes)
         self.width = width
         key = bkey.key
@@ -159,30 +156,35 @@ class BatchRunner:
         """Execute the next fence block (ONE vmapped call for the whole
         batch); returns ``(lane_index, chunk)`` telemetry deltas."""
         assert not self.done
-        phase, cycles = self.schedule[self.idx]
-        key = self.bkey.key
-        self.sim_compiles += _note(
-            ("block", key, cycles, self.width, self.bkey.prog_len))
-        self.states = _block_jit(key, cycles)(self.progs, self.states)
-        n = len(self.lanes)
-        inj, comp, util = self._snapshot()
-        hist = np.asarray(self.states.lat_hist)[:n]
-        deliv = hist.sum(-1).astype(np.int64)
-        out = [(i, StreamChunk(
-            phase=phase, start=self.cycle, stop=self.cycle + cycles,
-            injected=int(inj[i] - self._prev_inj[i]),
-            completed=int(comp[i] - self._prev_comp[i]),
-            delivered=int(deliv[i] - self._prev_deliv[i]),
-            hist=hist[i] - self._prev_hist[i])) for i in range(n)]
-        self._prev_inj, self._prev_comp = inj, comp
-        self._prev_deliv, self._prev_hist = deliv, hist
-        self.cycle += cycles
-        self.idx += 1
-        nxt = self.schedule[self.idx][0] if not self.done else None
-        if phase == "warmup" and nxt != "warmup":
-            self._snap_w = self._snap_m = (inj, comp, util)
-        elif phase == "measure" and nxt != "measure":
-            self._snap_m = (inj, comp, util)
+        with tracing.span("sim_service.block", batch=self.batch):
+            phase, cycles = self.schedule[self.idx]
+            key = self.bkey.key
+            self.sim_compiles += _note(
+                ("block", key, cycles, self.width, self.bkey.prog_len))
+            self.states = _block_jit(key, cycles)(self.progs, self.states)
+            # the wait the first read-back would make, made explicit so
+            # the block's device time has a span of its own
+            with tracing.span("sim_service.block.wait"):
+                jax.block_until_ready(self.states)
+            n = len(self.lanes)
+            inj, comp, util = self._snapshot()
+            hist = np.asarray(self.states.lat_hist)[:n]
+            deliv = hist.sum(-1).astype(np.int64)
+            out = [(i, StreamChunk(
+                phase=phase, start=self.cycle, stop=self.cycle + cycles,
+                injected=int(inj[i] - self._prev_inj[i]),
+                completed=int(comp[i] - self._prev_comp[i]),
+                delivered=int(deliv[i] - self._prev_deliv[i]),
+                hist=hist[i] - self._prev_hist[i])) for i in range(n)]
+            self._prev_inj, self._prev_comp = inj, comp
+            self._prev_deliv, self._prev_hist = deliv, hist
+            self.cycle += cycles
+            self.idx += 1
+            nxt = self.schedule[self.idx][0] if not self.done else None
+            if phase == "warmup" and nxt != "warmup":
+                self._snap_w = self._snap_m = (inj, comp, util)
+            elif phase == "measure" and nxt != "measure":
+                self._snap_m = (inj, comp, util)
         return out
 
     def finalize(self) -> List[PhaseStats]:
