@@ -13,7 +13,7 @@ executes several mesh cycles per launch, amortizing the dispatch the way
 Design notes:
 
 * **Shared trace.** The kernel body calls ``_step_core(kernel_safe=True)``
-  — the very same function the fused XLA path runs, with its four
+  — the very same function the fused XLA path runs, with its remaining
   traced-index scatter/gather ops swapped for one-hot select/sum forms
   and its bool reshapes widened to int32 (both exact), which Mosaic
   lowers.  There is no second implementation of the router to

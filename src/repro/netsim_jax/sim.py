@@ -596,6 +596,20 @@ def _coords(cfg: SimConfig, kernel_safe: bool = False):
     return xs.astype(np.int32), ys.astype(np.int32)
 
 
+# Longest program whose next entry the fused step fetches by one-hot
+# select rather than by gather (see _step_core).  On a v5e at 16x32 the
+# one-hot is the faster up to 2,048 entries and the slower from 3,072
+# (benchmarks/entry_fetch_crossover.py).
+ENTRY_ONEHOT_MAX_LP = 2048
+
+
+def _entry_fetch_onehot(Lp: int, kernel_safe: bool) -> bool:
+    """Whether a program of ``Lp`` entries per tile is fetched by one-hot
+    select: always inside the Pallas kernel (Mosaic lowers no gather),
+    elsewhere up to :data:`ENTRY_ONEHOT_MAX_LP` entries."""
+    return kernel_safe or Lp <= ENTRY_ONEHOT_MAX_LP
+
+
 def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
                kernel_safe: bool = False) -> Tuple[SimState, jax.Array]:
     """One simulator cycle; returns (state', completions_this_cycle).
@@ -607,16 +621,23 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     performed as ONE stacked write at the end — legal because nothing in
     between reads the router buffers, only the counts.
 
+    The endpoint's memory read is a one-hot select over ``mem_words``
+    on both paths, reusing the write's mask.  The program-entry fetch is
+    one where :func:`_entry_fetch_onehot` says so (always in the kernel;
+    on the fused path up to :data:`ENTRY_ONEHOT_MAX_LP` entries): on a
+    TPU an element-wise gather along the minor axis costs about 60 ns
+    per tile whatever the length, while the one-hot streams the whole
+    program every cycle.
+
     ``kernel_safe=True`` is the variant traced inside the Pallas router
-    kernel (:mod:`repro.kernels.router_step`): the four traced-index
-    scatter/gather ops (the latency-histogram ``.at[].add``, the memory
-    read, the program-entry fetch and the ``resp_latency > 1`` slot
-    rotation) are swapped for one-hot select/sum forms, and bool masks
-    are reshaped and stacked as int32 (:func:`_expand`,
+    kernel (:mod:`repro.kernels.router_step`): the remaining
+    traced-index scatter/gather ops (the latency-histogram ``.at[].add``,
+    the program-entry fetch of long programs and the ``resp_latency > 1``
+    slot rotation) are swapped for one-hot select/sum forms, and bool
+    masks are reshaped and stacked as int32 (:func:`_expand`,
     :func:`_stack_last`) — Mosaic lowers neither scatters nor bool
     relayouts.  All of it is exact int32 arithmetic, so the two variants
-    are bit-identical.  The default keeps XLA's native scatter/gather,
-    which is faster outside the kernel.
+    are bit-identical.
     """
     ny, nx = cfg.ny, cfg.nx
     xs, ys = _coords(cfg, kernel_safe)
@@ -705,13 +726,8 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
         addr = jnp.clip(req[_FI["addr"]], 0, cfg.mem_words - 1)
         addr_oh = _iota_last(addr.shape, cfg.mem_words, kernel_safe) \
             == addr[..., None]
-        if kernel_safe:
-            # one-hot read reusing the write mask (exact: int32, one hot
-            # bit)
-            cur = jnp.where(addr_oh, st.mem, 0).sum(-1)
-        else:
-            cur = jnp.take_along_axis(st.mem, addr[..., None],
-                                      axis=-1)[..., 0]
+        # one-hot read reusing the write mask (exact: int32, one hot bit)
+        cur = jnp.where(addr_oh, st.mem, 0).sum(-1)
         is_store = can & (req_op == OP_STORE)
         is_load = can & (req_op == OP_LOAD)
         is_cas = can & (req_op == OP_CAS)
@@ -767,15 +783,17 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
         can_inj = pending & (credits > 0)
         Lp = prog.buf.shape[-1]
         pidx = jnp.clip(st.prog_ptr, 0, max(Lp - 1, 0))
-        if kernel_safe:
-            lp_oh = _iota_last(pidx.shape, Lp, True) == pidx[..., None]
-            # (|PROG|, ny, nx)
-            entry = jnp.where(lp_oh[None], prog.buf, 0).sum(-1)
-        else:
-            entry = jnp.take_along_axis(
-                prog.buf, jnp.broadcast_to(pidx[None, ..., None],
-                                           (len(PROG_FIELDS), ny, nx, 1)),
-                axis=-1)[..., 0]                        # (|PROG|, ny, nx)
+        with jax.named_scope("fetch"):               # step/inject/fetch
+            if _entry_fetch_onehot(Lp, kernel_safe):
+                lp_oh = _iota_last(pidx.shape, Lp, kernel_safe) \
+                    == pidx[..., None]
+                # (|PROG|, ny, nx); exact: int32, one hot bit
+                entry = jnp.where(lp_oh[None], prog.buf, 0).sum(-1)
+            else:
+                entry = jnp.take_along_axis(
+                    prog.buf, jnp.broadcast_to(pidx[None, ..., None],
+                                               (len(PROG_FIELDS), ny, nx, 1)),
+                    axis=-1)[..., 0]                    # (|PROG|, ny, nx)
         can_inj = can_inj & (entry[_PI["not_before"]] <= c)
         can_inj = can_inj & (fwd_count[..., P] < st.fifo_depth)
         pkt = jnp.stack([

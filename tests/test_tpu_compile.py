@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.router_step import VMEM_LIMIT_BYTES, router_step_call
 from repro.netsim_jax.measure import SweepKey, batch_stats_fn
 from repro.netsim_jax.sim import (I32, PROG_FIELDS, Program, SimConfig,
-                                  init_state, simulate)
+                                  init_state, run_until_drained_traced,
+                                  simulate)
 
 
 @pytest.fixture(scope="module")
@@ -74,6 +75,18 @@ def test_fused_simulate_compiles(one_chip, no_persistent_cache, nx, ny):
     compiled = simulate.lower(cfg, _on(one_chip, _program(cfg, 64)),
                               _on(one_chip, state), 64).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_fused_drain_compiles_without_gather(one_chip, no_persistent_cache):
+    """The drain benchmark's program (16x32, 128 entries per tile, fence
+    every cycle) fetches program entries and memory words by one-hot
+    select: the compiled program holds no gather."""
+    cfg = _cfg(16, 32)
+    state = jax.eval_shape(lambda: init_state(cfg))
+    compiled = run_until_drained_traced.lower(
+        cfg, _on(one_chip, _program(cfg, 128)), _on(one_chip, state),
+        100_000, 1, "fused", 1).compile()
+    assert "gather(" not in compiled.as_text()
 
 
 def test_batched_bucket_compiles(one_chip, no_persistent_cache):
