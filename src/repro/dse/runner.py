@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.compat import device_mesh_1d
 from repro.mesh.traffic import make_traffic
 from repro.netsim_jax.measure import (PhaseStats, SweepKey, batch_stats_fn,
@@ -82,6 +83,7 @@ class SweepResult:
     compiles: int
     devices: int
     wall_s: float
+    padded_rows: int    # rows added to fill whole per-device chunks
 
     def by_point(self) -> Dict[SweepPoint, Dict]:
         return {_point_from_record(r): r for r in self.records}
@@ -109,10 +111,10 @@ def _bucket_jit(key: SweepKey, ndev: int, chunk: int):
     vmapped slices) and, for ``ndev > 1``, sharded over a 1-D device
     mesh.  Cached per (key, fan-out) like every other sweep program —
     :func:`repro.netsim_jax.measure.clear_sweep_cache` clears it too."""
-    base = batch_stats_fn(key)
+    base = batch_stats_fn(key, digest=True)
 
     def chunked(progs: Program, depths: jax.Array,
-                credits: jax.Array) -> PhaseStats:
+                credits: jax.Array) -> Tuple[PhaseStats, jax.Array]:
         def split(x):
             return x.reshape((-1, chunk) + x.shape[1:])
 
@@ -159,46 +161,58 @@ def _bucket_programs(spec: SweepSpec, pts: Sequence[SweepPoint],
         else:
             progs.append(load_program(make_traffic(
                 p.traffic, p.nx, p.ny, length, rate=p.load, seed=p.seed,
-                topology=p.topology)))
+                topology=p.topology, mem_words=p.mem_words)))
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *progs)
 
 
 def _run_bucket(spec: SweepSpec, key: SweepKey, length: int,
                 pts: Sequence[SweepPoint], ndev: int,
-                chunk: int) -> Tuple[List[Dict], int]:
-    """Simulate one bucket; returns (per-point stat dicts, new compiles)."""
+                chunk: int) -> Tuple[List[Dict], List[List[int]], int, int]:
+    """Simulate one bucket; returns (per-point stat dicts, per-point
+    memory digests, new compiles, padded rows)."""
     n = len(pts)
     padded, eff = _pad_rows(n, ndev, chunk)
-    progs = _bucket_programs(spec, pts, length)
-    depths = np.fromiter((p.fifo_depth for p in pts), np.int32, n)
-    credits = np.fromiter((p.credits for p in pts), np.int32, n)
-    if padded > n:  # repeat the first point; its rows are dropped below
-        pad = padded - n
+    with tracing.span("dse.bucket.build", points=n):
+        progs = _bucket_programs(spec, pts, length)
+        depths = np.fromiter((p.fifo_depth for p in pts), np.int32, n)
+        credits = np.fromiter((p.credits for p in pts), np.int32, n)
+        if padded > n:  # repeat the first point; its rows are dropped below
+            pad = padded - n
 
-        def grow(x):
-            return jnp.concatenate(
-                [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])])
-        progs = jax.tree_util.tree_map(grow, progs)
-        depths = np.concatenate([depths, np.repeat(depths[:1], pad)])
-        credits = np.concatenate([credits, np.repeat(credits[:1], pad)])
+            def grow(x):
+                return jnp.concatenate(
+                    [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])])
+            progs = jax.tree_util.tree_map(grow, progs)
+            depths = np.concatenate([depths, np.repeat(depths[:1], pad)])
+            credits = np.concatenate([credits, np.repeat(credits[:1], pad)])
+        depths, credits = jnp.asarray(depths, I32), jnp.asarray(credits, I32)
     shape_id = (key, ndev, eff, padded, length)
     fresh = shape_id not in _EXECUTED_SHAPES
     _EXECUTED_SHAPES.add(shape_id)
-    stats = _bucket_jit(key, ndev, eff)(
-        progs, jnp.asarray(depths, I32), jnp.asarray(credits, I32))
-    host = {f: np.asarray(getattr(stats, f))[:n] for f in STAT_FIELDS}
-    return [{f: float(host[f][i]) for f in STAT_FIELDS}
-            for i in range(n)], int(fresh)
+    stats, digests = _bucket_jit(key, ndev, eff)(progs, depths, credits)
+    with tracing.span("dse.bucket.wait"):
+        jax.block_until_ready((stats, digests))
+    with tracing.span("dse.bucket.pull"):
+        host = {f: np.asarray(getattr(stats, f))[:n] for f in STAT_FIELDS}
+        digests = np.asarray(digests)[:n].tolist()
+    return ([{f: float(host[f][i]) for f in STAT_FIELDS} for i in range(n)],
+            digests, int(fresh), padded - n)
 
 
-def _point_record(point: SweepPoint, stats: Dict[str, float]) -> Dict:
+def _point_record(point: SweepPoint, stats: Dict[str, float],
+                  digest: List[int]) -> Dict:
+    """The persisted record of one point; ``mem_digest`` sums up its
+    final tile memory (:func:`repro.netsim_jax.sim.memory_digest`)."""
     return {
         "point": {"nx": point.nx, "ny": point.ny,
                   "topology": point.topology.spec,
                   "fifo_depth": point.fifo_depth, "credits": point.credits,
                   "traffic": point.traffic, "load": point.load,
-                  "seed": point.seed},
+                  "seed": point.seed, "ep_fifo": point.ep_fifo,
+                  "mem_words": point.mem_words,
+                  "resp_latency": point.resp_latency},
         "stats": {k: round(v, 6) for k, v in stats.items()},
+        "mem_digest": digest,
     }
 
 
@@ -209,7 +223,9 @@ def _point_from_record(record: Dict) -> SweepPoint:
                       topology=Topology.parse(p["topology"]),
                       fifo_depth=p["fifo_depth"], credits=p["credits"],
                       traffic=p["traffic"], load=p["load"],
-                      seed=p.get("seed", 0))
+                      seed=p.get("seed", 0), ep_fifo=p.get("ep_fifo", 4),
+                      mem_words=p.get("mem_words", 64),
+                      resp_latency=p.get("resp_latency", 1))
 
 
 def run_sweep(spec: SweepSpec, *, cache_dir=None,
@@ -224,50 +240,54 @@ def run_sweep(spec: SweepSpec, *, cache_dir=None,
     the persistent compile cache
     (:func:`repro.compat.enable_persistent_compilation_cache`) reuses
     its bucket executables across restarts."""
-    t0 = time.perf_counter()
-    log = progress if progress is not None else (lambda msg: None)
-    cache = cache_dir if isinstance(cache_dir, ResultCache) \
-        else ResultCache(cache_dir)
-    points = spec.points()
-    infeasible = [f"skipped {t.spec} fifo_depth={d}: {why}"
-                  for t, d, why in spec.infeasible()]
-    for line in infeasible:
-        log(line)
-    log(spec.describe())
+    with tracing.span("dse.sweep", sweep=spec.name):
+        t0 = time.perf_counter()
+        log = progress if progress is not None else (lambda msg: None)
+        cache = cache_dir if isinstance(cache_dir, ResultCache) \
+            else ResultCache(cache_dir)
+        points = spec.points()
+        infeasible = [f"skipped {t.spec} fifo_depth={d}: {why}"
+                      for t, d, why in spec.infeasible()]
+        for line in infeasible:
+            log(line)
+        log(spec.describe())
 
-    done: Dict[SweepPoint, Dict] = {}
-    misses: List[SweepPoint] = []
-    for p in points:
-        rec = cache.get(spec.point_key(p))
-        if rec is not None:
-            done[p] = rec
-        else:
-            misses.append(p)
-    ndev = _resolve_devices(devices)
+        done: Dict[SweepPoint, Dict] = {}
+        misses: List[SweepPoint] = []
+        for p in points:
+            rec = cache.get(spec.point_key(p))
+            if rec is not None:
+                done[p] = rec
+            else:
+                misses.append(p)
+        ndev = _resolve_devices(devices)
 
-    buckets: Dict[Tuple[SweepKey, int], List[SweepPoint]] = {}
-    for p in misses:
-        length = workload_entries(p.family, p.nx, p.ny, p.seed)[
-            "op"].shape[-1] if p.is_workload else spec.traffic_length()
-        buckets.setdefault((spec.sweep_key(p.topology), int(length)),
-                           []).append(p)
+        buckets: Dict[Tuple[SweepKey, int], List[SweepPoint]] = {}
+        for p in misses:
+            length = workload_entries(p.family, p.nx, p.ny, p.seed)[
+                "op"].shape[-1] if p.is_workload else spec.traffic_length()
+            buckets.setdefault((spec.sweep_key(p.topology), int(length)),
+                               []).append(p)
 
-    compiles = 0
-    for (key, length), pts in buckets.items():
-        log(f"bucket {key.cfg.topology.spec} L={length}: {len(pts)} points "
-            f"({ndev} device(s), chunk {chunk})")
-        stats, fresh = _run_bucket(spec, key, length, pts, ndev, chunk)
-        compiles += fresh
-        for p, s in zip(pts, stats):
-            rec = _point_record(p, s)
-            cache.put(spec.point_key(p), rec)
-            done[p] = rec
+        compiles = padded = 0
+        for (key, length), pts in buckets.items():
+            log(f"bucket {key.cfg.topology.spec} L={length}: {len(pts)} "
+                f"points ({ndev} device(s), chunk {chunk})")
+            stats, digests, fresh, pad = _run_bucket(spec, key, length, pts,
+                                                     ndev, chunk)
+            compiles += fresh
+            padded += pad
+            for p, s, d in zip(pts, stats, digests):
+                rec = _point_record(p, s, d)
+                cache.put(spec.point_key(p), rec)
+                done[p] = rec
 
-    return SweepResult(
-        spec=spec, records=[done[p] for p in points], n_points=len(points),
-        simulated=len(misses), cache_hits=len(points) - len(misses),
-        infeasible=infeasible, buckets=len(buckets), compiles=compiles,
-        devices=ndev, wall_s=round(time.perf_counter() - t0, 2))
+        return SweepResult(
+            spec=spec, records=[done[p] for p in points], n_points=len(points),
+            simulated=len(misses), cache_hits=len(points) - len(misses),
+            infeasible=infeasible, buckets=len(buckets), compiles=compiles,
+            devices=ndev, wall_s=round(time.perf_counter() - t0, 2),
+            padded_rows=padded)
 
 
 # -- frontier extraction -----------------------------------------------

@@ -78,6 +78,10 @@ class SweepPoint:
     traffic: str
     load: float = 0.0
     seed: int = 0
+    # the deployment's endpoint and memory sizes (MeshConfig's defaults)
+    ep_fifo: int = 4
+    mem_words: int = 64
+    resp_latency: int = 1
 
     @property
     def is_workload(self) -> bool:
@@ -95,6 +99,8 @@ class SweepPoint:
         return MeshConfig(nx=self.nx, ny=self.ny,
                           router_fifo=self.fifo_depth,
                           max_out_credits=self.credits,
+                          ep_fifo=self.ep_fifo, mem_words=self.mem_words,
+                          resp_latency=self.resp_latency,
                           topology=self.topology)
 
     def label(self) -> str:
@@ -108,7 +114,9 @@ class SweepSpec:
     """The declarative sweep: every axis a tuple, every point their cross
     product.  Topologies accept :class:`Topology` objects or their
     string form (``"torus"``, ``"multi_chip:2:4"``); validation is eager
-    and names the offending axis."""
+    and names the offending axis.  ``ep_fifo``, ``mem_words`` and
+    ``resp_latency`` are the swept deployment's endpoint FIFO, words of
+    memory per tile and response latency, the same at every point."""
     nx: int
     ny: int
     fifo_depths: Tuple[int, ...] = (2, 4, 8, 16)
@@ -125,6 +133,9 @@ class SweepSpec:
     impl: str = "fused"
     cycles_per_call: int = 1
     name: str = "sweep"
+    ep_fifo: int = 4
+    mem_words: int = 64
+    resp_latency: int = 1
 
     def __post_init__(self):
         dedupe = lambda xs: tuple(dict.fromkeys(xs))  # noqa: E731
@@ -165,18 +176,28 @@ class SweepSpec:
                 "family")
         if not self.topologies:
             raise ValueError("a sweep needs at least one topology")
+        for size in ("ep_fifo", "mem_words", "resp_latency"):
+            if getattr(self, size) < 1:
+                raise ValueError(f"{size} must be a positive int, got "
+                                 f"{getattr(self, size)}")
         for topo in self.topologies:
             # surfaces shape/topology mismatches (and the coord-field
             # limits) before any simulation happens
             MeshConfig(nx=self.nx, ny=self.ny,
                        router_fifo=max(max(self.fifo_depths),
                                        topo.min_router_fifo),
-                       max_out_credits=max(self.credits), topology=topo)
+                       max_out_credits=max(self.credits), topology=topo,
+                       **self.deployment())
 
     # -- expansion ------------------------------------------------------
     @property
     def horizon(self) -> int:
         return self.warmup + self.measure + self.drain
+
+    def deployment(self) -> Dict[str, int]:
+        """The endpoint and memory sizes every point shares."""
+        return {"ep_fifo": self.ep_fifo, "mem_words": self.mem_words,
+                "resp_latency": self.resp_latency}
 
     def feasible_depths(self, topology: Topology) -> Tuple[int, ...]:
         return tuple(d for d in self.fifo_depths
@@ -192,11 +213,12 @@ class SweepSpec:
                         for load in self.loads:
                             out.append(SweepPoint(
                                 self.nx, self.ny, topo, depth, cred, pat,
-                                load, self.seed))
+                                load, self.seed, **self.deployment()))
                     for fam in self.workloads:
                         out.append(SweepPoint(
                             self.nx, self.ny, topo, depth, cred,
-                            _WL_PREFIX + fam, 0.0, self.seed))
+                            _WL_PREFIX + fam, 0.0, self.seed,
+                            **self.deployment()))
         return out
 
     def infeasible(self) -> List[Tuple[Topology, int, str]]:
@@ -224,7 +246,7 @@ class SweepSpec:
                 f"in {self.fifo_depths}")
         return MeshConfig(nx=self.nx, ny=self.ny, router_fifo=max(depths),
                           max_out_credits=max(self.credits),
-                          topology=topology)
+                          topology=topology, **self.deployment())
 
     def sweep_key(self, topology: Topology) -> SweepKey:
         """The compiled-program identity of ``topology``'s bucket —
@@ -263,5 +285,7 @@ class SweepSpec:
                 f"{len(self.credits)} credits x "
                 f"({len(self.patterns)} patterns x {len(self.loads)} loads"
                 f" + {len(self.workloads)} workloads)")
-        return (f"sweep {self.name!r}: {self.nx}x{self.ny}, {axes} = "
+        return (f"sweep {self.name!r}: {self.nx}x{self.ny} "
+                f"(mem {self.mem_words}, ep {self.ep_fifo}, "
+                f"lat {self.resp_latency}), {axes} = "
                 f"{n} feasible points, horizon {self.horizon} cycles")

@@ -257,9 +257,9 @@ class Simulator:
         if self.backend == "numpy":
             self._sim.mem[:] = self._mem0
         else:
-            import jax.numpy as jnp
+            from repro.netsim_jax.sim import mem_to_state
             self._sim.state = self._sim.state._replace(
-                mem=jnp.asarray(self._mem0, jnp.int32))
+                mem=mem_to_state(self._sim.cfg, self._mem0))
             if self._oracle is not None:
                 self._oracle.set_mem(self._mem0)
 
@@ -368,9 +368,9 @@ class Simulator:
         prog = self.injection_trace_program()
         self._sim = self._make_backend()
         if self._mem0 is not None:
-            import jax.numpy as jnp
+            from repro.netsim_jax.sim import mem_to_state
             self._sim.state = self._sim.state._replace(
-                mem=jnp.asarray(self._mem0, jnp.int32))
+                mem=mem_to_state(self._sim.cfg, self._mem0))
         if self._window is not None:
             self._sim.set_measure_window(*self._window)
         self._sim.load_program(prog)

@@ -43,7 +43,7 @@ import numpy as np
 from repro.core.netsim import LAT_BINS
 from repro.mesh.config import MeshConfig
 from .sim import (FWD, I32, Program, SimConfig, SimState, init_state,
-                  load_program, simulate)
+                  load_program, memory_digest, simulate)
 from .traffic import make_traffic
 
 __all__ = ["PhaseStats", "phased_stats", "measure_program",
@@ -196,6 +196,14 @@ def phased_stats(cfg: SimConfig, prog: Program, state: SimState,
     XLA step vs the Pallas router kernel and its cycles-per-launch) —
     speed knobs that never change results.  No buffer donation here: the
     reduced stats are tiny, so the state has no output to alias with."""
+    return _phased(cfg, prog, state, warmup, measure, drain, unroll, impl,
+                   cycles_per_call)[0]
+
+
+def _phased(cfg: SimConfig, prog: Program, state: SimState, warmup: int,
+            measure: int, drain: int, unroll: int, impl: str,
+            cycles_per_call: int) -> Tuple[PhaseStats, SimState]:
+    """:func:`phased_stats` outside its ``jit``, with the final state."""
     ntiles = cfg.nx * cfg.ny
     st = state._replace(
         measure_start=state.cycle + warmup,
@@ -208,7 +216,8 @@ def phased_stats(cfg: SimConfig, prog: Program, state: SimState,
     util1 = st.link_util
     st, _ = simulate(cfg, prog, st, drain, unroll, impl, cycles_per_call)
     return reduce_window_stats(ntiles, measure, st.lat_hist,
-                               inj1 - inj0, comp1 - comp0, util1 - util0)
+                               inj1 - inj0, comp1 - comp0,
+                               util1 - util0), st
 
 
 # -- per-fence-block streaming -------------------------------------------
@@ -431,24 +440,31 @@ def _sweep_jit(key: SweepKey):
     return jax.jit(f)
 
 
-def batch_stats_fn(key: SweepKey):
+def batch_stats_fn(key: SweepKey, digest: bool = False):
     """The *traceable* batched phased-measurement function for ``key``:
     ``f(progs, fifo_depths, max_credits) -> PhaseStats``, every argument
     and result carrying a leading batch axis.  ``fifo_depths`` /
     ``max_credits`` are the per-point *dynamic* knobs (must not exceed
     the static ``key.cfg`` capacities); each batch element runs from a
-    fresh state.  Returned untransformed so callers can compose it with
+    fresh state.  With ``digest``, ``f`` returns ``(PhaseStats,
+    digests)``, the second each point's final tile memory summed up by
+    :func:`~repro.netsim_jax.sim.memory_digest`, ``(batch, 2)`` uint32.
+    Returned untransformed so callers can compose it with
     ``jit`` / ``lax.map`` chunking / ``shard_map`` fan-out — the DSE
     runner (:mod:`repro.dse.runner`) does all three."""
     cfg = key.cfg
+    phases = (key.warmup, key.measure, key.drain, key.unroll, key.impl,
+              key.cycles_per_call)
 
-    def f(progs: Program, fifo_depths: jax.Array,
-          max_credits: jax.Array) -> PhaseStats:
-        return jax.vmap(
-            lambda p, d, c: phased_stats(
-                cfg, p, init_state(cfg, d, c), key.warmup, key.measure,
-                key.drain, key.unroll, key.impl, key.cycles_per_call))(
-            progs, fifo_depths, max_credits)
+    def one(prog: Program, depth: jax.Array, credits: jax.Array):
+        state = init_state(cfg, depth, credits)
+        if not digest:
+            return phased_stats(cfg, prog, state, *phases)
+        stats, st = _phased(cfg, prog, state, *phases)
+        return stats, memory_digest(cfg, st.mem)
+
+    def f(progs: Program, fifo_depths: jax.Array, max_credits: jax.Array):
+        return jax.vmap(one)(progs, fifo_depths, max_credits)
     return f
 
 

@@ -189,7 +189,7 @@ class SimState(NamedTuple):
     ep_in: Fifo
     resp_valid: jax.Array      # (L, ny, nx) bool
     resp_buf: jax.Array        # (F, L, ny, nx)
-    mem: jax.Array             # (ny, nx, mem_words)
+    mem: jax.Array             # (ny, nx, mem_words), or rows: mem_shape
     credits: jax.Array         # (ny, nx)
     rr: jax.Array              # (2, ny, nx, 5) round-robin ptrs per network
     prog_ptr: jax.Array        # (ny, nx)
@@ -232,7 +232,7 @@ def init_state(cfg: SimConfig,
                    count=jnp.zeros((ny, nx, 1), I32)),
         resp_valid=jnp.zeros((L, ny, nx), bool),
         resp_buf=jnp.zeros((F, L, ny, nx), I32),
-        mem=jnp.zeros((ny, nx, cfg.mem_words), I32),
+        mem=jnp.zeros(mem_shape(cfg), I32),
         credits=jnp.broadcast_to(mc, (ny, nx)).astype(I32),
         rr=jnp.zeros((2, ny, nx, NUM_DIRS), I32),
         prog_ptr=jnp.zeros((ny, nx), I32),
@@ -610,6 +610,73 @@ def _entry_fetch_onehot(Lp: int, kernel_safe: bool) -> bool:
     return kernel_safe or Lp <= ENTRY_ONEHOT_MAX_LP
 
 
+# Largest tile memory (words per tile) the fused step reads and writes by
+# one-hot select rather than by gathering and scattering one word per
+# tile (see _step_core).  On a v5e at 32x32, a batch of 16, the one-hot
+# is the faster up to 3,072 words and the slower from 4,096
+# (benchmarks/mem_access_crossover.py); the TPU's scatter still rewrites
+# the whole memory, so neither form's cost is flat in mem_words.
+MEM_ONEHOT_MAX_WORDS = 3072
+# A larger memory is stored as rows of MEM_ROW words, a whole number of
+# (8, MEM_ROW) int32 tiles per tile of the mesh: so laid out, a batch of
+# states flattens to the one dimension the TPU's gather and scatter
+# index without a copy of the memory.
+MEM_ROW = 128
+
+
+def _mem_onehot(mem_words: int, kernel_safe: bool) -> bool:
+    """Whether a tile memory of ``mem_words`` words is read and written
+    by one-hot select: always inside the Pallas kernel (Mosaic lowers
+    neither gather nor scatter), elsewhere up to
+    :data:`MEM_ONEHOT_MAX_WORDS` words."""
+    return kernel_safe or mem_words <= MEM_ONEHOT_MAX_WORDS
+
+
+def mem_shape(cfg: SimConfig) -> Tuple[int, ...]:
+    """``SimState.mem``'s shape: ``(ny, nx, mem_words)`` up to
+    :data:`MEM_ONEHOT_MAX_WORDS` words, else ``(ny, nx, rows, MEM_ROW)``
+    with the words in order and unaddressed padding at the end."""
+    if _mem_onehot(cfg.mem_words, kernel_safe=False):
+        return (cfg.ny, cfg.nx, cfg.mem_words)
+    rows = -(-cfg.mem_words // (8 * MEM_ROW)) * 8
+    return (cfg.ny, cfg.nx, rows, MEM_ROW)
+
+
+def mem_to_state(cfg: SimConfig, words) -> jax.Array:
+    """A ``(ny, nx, mem_words)`` memory image in ``SimState.mem``'s shape."""
+    words = jnp.asarray(words, I32)
+    shape = mem_shape(cfg)
+    pad = int(np.prod(shape[2:])) - cfg.mem_words
+    return jnp.pad(words, ((0, 0), (0, 0), (0, pad))).reshape(shape)
+
+
+def mem_from_state(cfg: SimConfig, mem) -> np.ndarray:
+    """``SimState.mem`` as the ``(ny, nx, mem_words)`` memory image."""
+    return np.asarray(mem, np.int64).reshape(
+        cfg.ny, cfg.nx, -1)[..., :cfg.mem_words]
+
+
+def memory_digest(cfg: SimConfig, mem: jax.Array) -> jax.Array:
+    """Two uint32 words that sum up one state's ``SimState.mem``, mod
+    2**32: the sum of its words, and the sum of each word times one more
+    than its place in the ``(ny, nx, mem_words)`` image.  A store that
+    is dropped, or written to another word, moves the second."""
+    u32 = jnp.uint32
+    words = lax.bitcast_convert_type(mem, u32)
+    # place = tile * mem_words + word + 1, summed per tile first so that
+    # no array of places as large as the memory is made; padding words
+    # past mem_words hold 0, whatever place they get
+    in_tile = tuple(range(2, mem.ndim))
+    iota = functools.partial(lax.broadcasted_iota, u32, mem.shape[2:])
+    word = iota(0) if mem.ndim == 3 else iota(0) * MEM_ROW + iota(1)
+    tile = lax.broadcasted_iota(u32, mem.shape[:2], 0) * cfg.nx \
+        + lax.broadcasted_iota(u32, mem.shape[:2], 1)
+    total = words.sum(in_tile, dtype=u32)
+    placed = total * (tile * cfg.mem_words + 1) \
+        + (words * word).sum(in_tile, dtype=u32)
+    return jnp.stack([total.sum(dtype=u32), placed.sum(dtype=u32)])
+
+
 def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
                kernel_safe: bool = False) -> Tuple[SimState, jax.Array]:
     """One simulator cycle; returns (state', completions_this_cycle).
@@ -621,23 +688,26 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     performed as ONE stacked write at the end — legal because nothing in
     between reads the router buffers, only the counts.
 
-    The endpoint's memory read is a one-hot select over ``mem_words``
-    on both paths, reusing the write's mask.  The program-entry fetch is
-    one where :func:`_entry_fetch_onehot` says so (always in the kernel;
-    on the fused path up to :data:`ENTRY_ONEHOT_MAX_LP` entries): on a
-    TPU an element-wise gather along the minor axis costs about 60 ns
-    per tile whatever the length, while the one-hot streams the whole
-    program every cycle.
+    The endpoint's memory read and write are one-hot selects over
+    ``mem_words`` where :func:`_mem_onehot` says so (always in the
+    kernel; on the fused path up to :data:`MEM_ONEHOT_MAX_WORDS` words),
+    else one word gathered and one scattered per tile, from a memory
+    stored in rows (:func:`mem_shape`).  The
+    program-entry fetch is one where :func:`_entry_fetch_onehot` says so
+    (always in the kernel; on the fused path up to
+    :data:`ENTRY_ONEHOT_MAX_LP` entries).  On a TPU an element-wise
+    gather along the minor axis costs about the same whatever the
+    length, while a one-hot streams the whole array every cycle.
 
     ``kernel_safe=True`` is the variant traced inside the Pallas router
     kernel (:mod:`repro.kernels.router_step`): the remaining
     traced-index scatter/gather ops (the latency-histogram ``.at[].add``,
-    the program-entry fetch of long programs and the ``resp_latency > 1``
-    slot rotation) are swapped for one-hot select/sum forms, and bool
-    masks are reshaped and stacked as int32 (:func:`_expand`,
-    :func:`_stack_last`) — Mosaic lowers neither scatters nor bool
-    relayouts.  All of it is exact int32 arithmetic, so the two variants
-    are bit-identical.
+    the program-entry fetch of long programs, the access to large tile
+    memories and the ``resp_latency > 1`` slot rotation) are swapped for
+    one-hot select/sum forms, and bool masks are reshaped and stacked as
+    int32 (:func:`_expand`, :func:`_stack_last`) — Mosaic lowers neither
+    scatters nor bool relayouts.  All of it is exact int32 arithmetic,
+    so the two variants are bit-identical.
     """
     ny, nx = cfg.ny, cfg.nx
     xs, ys = _coords(cfg, kernel_safe)
@@ -724,17 +794,43 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
         req_hdr = req[_FI["hdr"]]
         req_op = (req_hdr >> OP_SHIFT) & OP_MASK
         addr = jnp.clip(req[_FI["addr"]], 0, cfg.mem_words - 1)
-        addr_oh = _iota_last(addr.shape, cfg.mem_words, kernel_safe) \
-            == addr[..., None]
-        # one-hot read reusing the write mask (exact: int32, one hot bit)
-        cur = jnp.where(addr_oh, st.mem, 0).sum(-1)
+        mem_onehot = _mem_onehot(cfg.mem_words, kernel_safe)
+        # memory stored in rows: one more axis after the tile's
+        rows = st.mem.ndim - 3
+        with jax.named_scope("mem"):                 # step/endpoint/mem
+            if mem_onehot:
+                if rows:      # inside the kernel: a word's place in rows
+                    word = lax.broadcasted_iota(I32, st.mem.shape, 2) \
+                        * MEM_ROW + lax.broadcasted_iota(I32, st.mem.shape, 3)
+                    addr_oh = word == addr[..., None, None]
+                else:
+                    addr_oh = _iota_last(addr.shape, cfg.mem_words,
+                                         kernel_safe) == addr[..., None]
+                # one-hot read reusing the write mask (exact: int32, one
+                # hot bit)
+                cur = jnp.where(addr_oh, st.mem, 0).sum(
+                    tuple(range(2, st.mem.ndim)))
+            else:
+                at = (ys, xs, addr // MEM_ROW, addr % MEM_ROW)
+                cur = st.mem.at[at].get(mode="promise_in_bounds")
         is_store = can & (req_op == OP_STORE)
         is_load = can & (req_op == OP_LOAD)
         is_cas = can & (req_op == OP_CAS)
         cas_hit = is_cas & (cur == req[_FI["cmp"]])
+        # the current word wherever nothing is stored
         newval = jnp.where(is_store | cas_hit, req[_FI["data"]], cur)
-        mem = jnp.where(addr_oh & _expand(can, -1, kernel_safe),
-                        newval[..., None], st.mem)
+        with jax.named_scope("mem"):
+            if mem_onehot:
+                can_w = _expand(can, -1, kernel_safe)
+                if rows:
+                    can_w = _expand(can_w, -1, kernel_safe)
+                mem = jnp.where(addr_oh & can_w,
+                                newval[(...,) + (None,) * (1 + rows)],
+                                st.mem)
+            else:
+                mem = st.mem.at[at].set(
+                    newval, indices_are_sorted=True, unique_indices=True,
+                    mode="promise_in_bounds")
         ep_in = _fifo_pop(st.ep_in, _expand(can, -1, kernel_safe),
                           jnp.asarray(cfg.ep_fifo, I32))
         rdata = jnp.where(is_load | is_cas, cur, 0)
@@ -1096,7 +1192,7 @@ class JaxMeshSim:
     # oracle-shaped accessors -----------------------------------------
     @property
     def mem(self) -> np.ndarray:
-        return np.asarray(self.state.mem, np.int64)
+        return mem_from_state(self.cfg, self.state.mem)
 
     @property
     def completed(self) -> np.ndarray:

@@ -8,6 +8,8 @@ graceful degradation, the resumable on-disk cache, and the cost/Pareto
 post-passes.  Simulation-heavy tests stay on 4x4 arrays with short
 phases — the methodology, not the numbers, is under test here.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -16,6 +18,9 @@ from repro.dse import (CostModel, ResultCache, SweepPoint, SweepSpec,
                        ascii_frontier, config_hash, frontier_artifact,
                        frontier_ascii, frontier_is_monotone, pareto_front,
                        run_sweep, workload_entries)
+from repro.dse.runner import (_bucket_programs, _point_from_record,
+                              _point_record)
+from repro.mesh import Simulator
 from repro.mesh.config import MeshConfig
 from repro.mesh.topology import Topology
 from repro.mesh.traffic import make_traffic
@@ -24,6 +29,14 @@ from repro.netsim_jax.measure import (SweepKey, batched_phased_stats,
 from repro.netsim_jax.sim import init_state, load_program
 
 PHASES = dict(warmup=50, measure=100, drain=100)
+
+
+def _digest(mem):
+    """``memory_digest`` of a ``(ny, nx, mem_words)`` memory, in numpy."""
+    words = (np.asarray(mem, np.int64) % 2**32).astype(np.uint64).ravel()
+    place = np.arange(1, words.size + 1, dtype=np.uint64)
+    return [int(words.sum() % 2**32),
+            int((words * place % 2**32).sum() % 2**32)]
 
 
 def small_spec(**kw):
@@ -95,6 +108,32 @@ class TestSweepSpec:
         q = SweepPoint(p.nx, p.ny, p.topology, p.fifo_depth, p.credits,
                        p.traffic, p.load, seed=7)
         assert spec.point_key(p) != spec.point_key(q)
+
+    def test_point_keys_and_records_carry_the_deployment(self):
+        small, large = small_spec(), small_spec(mem_words=4096, ep_fifo=2,
+                                                resp_latency=2)
+        topo = large.topologies[0]
+        cfg = large.bucket_config(topo)
+        assert (cfg.mem_words, cfg.ep_fifo, cfg.resp_latency) == (4096, 2, 2)
+        assert large.sweep_key(topo).cfg.mem_words == 4096
+        for p, q in zip(small.points(), large.points()):
+            assert small.point_key(p) != large.point_key(q)
+            assert q.mesh_config() == dataclasses.replace(
+                p.mesh_config(), mem_words=4096, ep_fifo=2, resp_latency=2)
+            rec = _point_record(q, {}, [0, 0])
+            assert rec["point"]["mem_words"] == 4096
+            assert _point_from_record(rec) == q
+            # a record written before the deployment's sizes were kept
+            old = _point_record(p, {}, [0, 0])
+            for size in ("ep_fifo", "mem_words", "resp_latency"):
+                del old["point"][size]
+            assert _point_from_record(old) == p
+
+    @pytest.mark.parametrize("size", ["ep_fifo", "mem_words",
+                                      "resp_latency"])
+    def test_deployment_sizes_must_be_positive(self, size):
+        with pytest.raises(ValueError, match=size):
+            small_spec(**{size: 0})
 
     def test_workload_entries_shapes(self):
         ent = workload_entries("allreduce", 4, 4)
@@ -186,6 +225,43 @@ class TestRunSweep:
                                  devices=jax.device_count() + 1, chunk=4)
         assert degraded.devices == 1
         assert degraded.records == base.records
+
+    def test_deployment_sizes_match_phased_stats_at_that_config(self):
+        """Each point of a sweep at a deployment's endpoint and memory
+        sizes equals ``phased_stats`` at the point's own MeshConfig, on
+        a program addressing words past the default 64, and its memory
+        digest sums up the oracle's final memory."""
+        spec = small_spec(mem_words=4096, ep_fifo=2, resp_latency=2,
+                          loads=(0.2, 0.6), patterns=("uniform", "hotspot"),
+                          seed=3)
+        length = spec.traffic_length()
+        assert length > 64
+        res = run_sweep(spec, cache_dir=None, chunk=4)
+        assert res.padded_rows == 0
+        progs = _bucket_programs(spec, spec.points(), length)
+        assert int(progs.buf[:, 1].max()) == length - 1   # the addr lane
+        for rec, p in zip(res.records, spec.points()):
+            cfg = p.mesh_config().to_sim()
+            entries = make_traffic(p.traffic, 4, 4, length, rate=p.load,
+                                   seed=p.seed, mem_words=4096)
+            want = phased_stats(cfg, load_program(entries), init_state(cfg),
+                                **PHASES)
+            assert rec["stats"] == {f: round(float(getattr(want, f)), 6)
+                                    for f in rec["stats"]}, p.label()
+            oracle = Simulator(p.mesh_config(), backend="numpy")
+            oracle.attach(entries)
+            oracle.run(spec.horizon)
+            assert rec["mem_digest"] == _digest(oracle.mem), p.label()
+
+    def test_padded_rows_are_counted(self):
+        if jax.device_count() < 2:
+            pytest.skip("needs >= 2 devices (conftest sets 8)")
+        spec = small_spec(fifo_depths=(2,), credits=(4,),
+                          loads=(0.1, 0.2, 0.3))      # 3 points
+        base = run_sweep(spec, cache_dir=None, chunk=4)
+        res = run_sweep(spec, cache_dir=None, devices=2, chunk=4)
+        assert (base.padded_rows, res.padded_rows) == (0, 1)
+        assert res.records == base.records
 
     def test_infeasible_reported_in_result(self, tmp_path):
         spec = small_spec(fifo_depths=(1, 2), topologies=("torus",))

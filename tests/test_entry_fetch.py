@@ -2,7 +2,8 @@
 
 Programs of up to ``ENTRY_ONEHOT_MAX_LP`` entries per tile fetch each
 tile's next entry by an exact one-hot select; longer ones keep XLA's
-gather, and the memory read is a one-hot select at every length.  Both
+gather, and the memory read of the 64 words here is a one-hot select
+(``tests/test_mem_access.py`` covers larger memories).  Both
 fetch forms must drain bit-identically to the numpy oracle, including a
 tile whose pointer reaches the end of the program (the clipped index)
 and tiles padded with ``op < 0`` entries.

@@ -12,6 +12,7 @@ module.  Each compile runs with the persistent compilation cache off (a
 TPU executable written there cannot be read back without a chip).
 """
 import os
+import re
 
 import jax
 import pytest
@@ -97,6 +98,32 @@ def test_batched_bucket_compiles(one_chip, no_persistent_cache):
     progs = _on(one_chip, _program(cfg, 64, batch=(8,)))
     knob = jax.ShapeDtypeStruct((8,), I32, sharding=one_chip)
     jax.jit(batch_stats_fn(key)).lower(progs, knob, knob).compile()
+
+
+# an op whose result spans every tile's whole memory of 16,384 words,
+# as words or as rows of 128: a one-hot access over the memory (select,
+# iota, compare) or a change of its layout (copy, reshape)
+WHOLE_MEMORY = re.compile(
+    r"= (?:s32|pred)\[[0-9,]*(?:,16384|,128,128)\]\{[^}]*\} "
+    r"(?:select|iota|compare|copy|reshape)\(")
+
+
+def test_deployment_memory_bucket_compiles_without_onehot(
+        one_chip, no_persistent_cache):
+    """The Epiphany-V sweep's bucket, one chip's chunk of 16 points at
+    32x32 with 16,384 words per tile and 121 entries per tile, compiles,
+    and reads and writes one word per tile: no one-hot over the whole
+    memory and no copy of it into another layout.  The memory digest
+    reads the whole memory once a point, as uint32."""
+    cfg = SimConfig(nx=32, ny=32, router_fifo=4, max_out_credits=32,
+                    mem_words=16384)
+    key = SweepKey(cfg, warmup=200, measure=400, drain=400)
+    progs = _on(one_chip, _program(cfg, 121, batch=(16,)))
+    knob = jax.ShapeDtypeStruct((16,), I32, sharding=one_chip)
+    text = jax.jit(batch_stats_fn(key, digest=True)).lower(
+        progs, knob, knob).compile().as_text()
+    assert "scatter(" in text
+    assert WHOLE_MEMORY.search(text) is None
 
 
 @pytest.mark.parametrize("n", [8, 16])
