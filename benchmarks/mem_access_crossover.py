@@ -1,21 +1,24 @@
 #!/usr/bin/env python3
-"""Time the fused step's two tile-memory access forms on the chip.
+"""Time the fused step's tile-memory access forms on the chip.
 
     python benchmarks/mem_access_crossover.py [--mem 64 1024 ...]
 
 For each tile memory size ``mem_words`` and each access form
 (``onehot``: select-sum read and masked select write over the whole
-memory; ``gather``: the memory stored in rows of ``sim.MEM_ROW`` words,
-one word gathered and one scattered per tile), runs
-``--cycles`` cycles of the fused step (``simulate``) vmapped over a
-batch of ``--batch`` points, as a design-space sweep runs them, on a
-32x32 mesh under uniform store traffic at rate 0.5 whose addresses cover
-the memory, and prints each pair's wall time per point-cycle, the best
-of ``--reps`` timed calls after a compiling one.  The form and the
-memory's layout are forced by setting ``sim.MEM_ONEHOT_MAX_WORDS``
-before each trace; the crossover is
-the largest size at which ``onehot`` is still the faster.  The last
-line of standard output is one JSON object.  Needs a TPU.
+memory; ``scatter``: the memory stored in rows of ``sim.MEM_ROW`` words,
+one word gathered and one scattered per tile; ``store_kernel``: the
+same rows and gather, the storing tiles' words written in place by the
+``tile_mem_store`` Pallas kernel), runs ``--cycles`` cycles of the
+fused step vmapped over a batch of ``--batch`` points, as a
+design-space sweep runs them, on a 32x32 mesh under uniform store
+traffic at rate 0.5 whose addresses cover the memory, and prints each
+form's wall time per point-cycle, the best of ``--reps`` timed calls
+after a compiling one, beside the form the step takes at that size
+(``sim.mem_access_form``).  The form and the memory's layout are forced
+by setting ``sim.MEM_ONEHOT_MAX_WORDS`` and ``sim._mem_store`` before
+each trace; the crossover is the largest size at which ``onehot`` is
+still the faster.  The last line of standard output is one JSON
+object.  Needs a TPU.
 """
 from __future__ import annotations
 
@@ -34,7 +37,10 @@ import numpy as np  # noqa: E402
 from repro.mesh import make_traffic  # noqa: E402
 from repro.netsim_jax import sim  # noqa: E402
 
-FORMS = {"onehot": 1 << 30, "gather": 0}
+# form: (MEM_ONEHOT_MAX_WORDS, the step's write of a memory in rows)
+FORMS = {"onehot": (1 << 30, sim._mem_store),
+         "scatter": (0, sim._mem_scatter),
+         "store_kernel": (0, sim._mem_store)}
 
 
 def per_point_cycle_us(cfg, progs, batch: int, cycles: int,
@@ -81,13 +87,18 @@ def main() -> None:
             ent["addr"] = (ent["addr"] * stride) % words
             progs.append(sim.load_program(ent))
         progs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *progs)
-        row = {"mem_words": words}
-        for form, limit in FORMS.items():
-            sim.MEM_ONEHOT_MAX_WORDS = limit
-            row[form] = per_point_cycle_us(cfg, progs, args.batch,
-                                           args.cycles, args.reps)
-        print(f"mem_words {words:6d}: onehot {row['onehot']:8.3f}, "
-              f"gather {row['gather']:8.3f} us per point-cycle", flush=True)
+        row = {"mem_words": words, "form": sim.mem_access_form(cfg)}
+        limit0, store0 = sim.MEM_ONEHOT_MAX_WORDS, sim._mem_store
+        for form, (limit, store) in FORMS.items():
+            sim.MEM_ONEHOT_MAX_WORDS, sim._mem_store = limit, store
+            try:
+                row[form] = per_point_cycle_us(cfg, progs, args.batch,
+                                               args.cycles, args.reps)
+            finally:
+                sim.MEM_ONEHOT_MAX_WORDS, sim._mem_store = limit0, store0
+        print(f"mem_words {words:6d} ({row['form']}): " + ", ".join(
+            f"{form} {row[form]:8.3f}" for form in FORMS)
+            + " us per point-cycle", flush=True)
         rows.append(row)
     print(json.dumps({"device": dev.device_kind, "batch": args.batch,
                       "cycles": args.cycles, "rows": rows}))

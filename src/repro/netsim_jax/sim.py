@@ -611,16 +611,15 @@ def _entry_fetch_onehot(Lp: int, kernel_safe: bool) -> bool:
 
 
 # Largest tile memory (words per tile) the fused step reads and writes by
-# one-hot select rather than by gathering and scattering one word per
-# tile (see _step_core).  On a v5e at 32x32, a batch of 16, the one-hot
-# is the faster up to 3,072 words and the slower from 4,096
-# (benchmarks/mem_access_crossover.py); the TPU's scatter still rewrites
-# the whole memory, so neither form's cost is flat in mem_words.
-MEM_ONEHOT_MAX_WORDS = 3072
+# one-hot select rather than by gathering one word per tile and writing
+# the storing tiles' words (see _step_core, _mem_store).  On a v5e at
+# 32x32, a batch of 16, the one-hot is the faster up to 1,024 words and
+# the slower from 2,048 (benchmarks/mem_access_crossover.py).
+MEM_ONEHOT_MAX_WORDS = 1024
 # A larger memory is stored as rows of MEM_ROW words, a whole number of
 # (8, MEM_ROW) int32 tiles per tile of the mesh: so laid out, a batch of
-# states flattens to the one dimension the TPU's gather and scatter
-# index without a copy of the memory.
+# states flattens without a copy of the memory to the one dimension the
+# TPU's gather indexes, and to the rows the store kernel copies.
 MEM_ROW = 128
 
 
@@ -630,6 +629,53 @@ def _mem_onehot(mem_words: int, kernel_safe: bool) -> bool:
     neither gather nor scatter), elsewhere up to
     :data:`MEM_ONEHOT_MAX_WORDS` words."""
     return kernel_safe or mem_words <= MEM_ONEHOT_MAX_WORDS
+
+
+def mem_access_form(cfg: SimConfig, platform: Optional[str] = None) -> str:
+    """How the fused step reads and writes ``cfg``'s tile memory on
+    ``platform`` (default: JAX's default backend): ``"onehot"`` up to
+    :data:`MEM_ONEHOT_MAX_WORDS` words; above, one word gathered per tile
+    and written by ``"store_kernel"`` on a TPU (:func:`_mem_store`) or
+    by ``"scatter"`` elsewhere."""
+    if _mem_onehot(cfg.mem_words, kernel_safe=False):
+        return "onehot"
+    platform = platform or jax.default_backend()
+    return "store_kernel" if platform == "tpu" else "scatter"
+
+
+def _mem_at(addr: jax.Array):
+    """Each tile's word ``addr`` as an index into a memory stored in
+    rows, ``(ny, nx, rows, MEM_ROW)``."""
+    ys, xs = np.mgrid[0:addr.shape[0], 0:addr.shape[1]].astype(np.int32)
+    return ys, xs, addr // MEM_ROW, addr % MEM_ROW
+
+
+def _mem_scatter(mem: jax.Array, addr: jax.Array, newval: jax.Array,
+                 write: jax.Array) -> jax.Array:
+    """Every tile's word ``addr`` set to ``newval`` by one XLA scatter;
+    where ``write`` is false ``newval`` is the word as it was."""
+    del write
+    return mem.at[_mem_at(addr)].set(
+        newval, indices_are_sorted=True, unique_indices=True,
+        mode="promise_in_bounds")
+
+
+def _mem_store_kernel(mem: jax.Array, addr: jax.Array, newval: jax.Array,
+                      write: jax.Array) -> jax.Array:
+    """The storing tiles' words written in place by the compiled
+    ``tile_mem_store`` kernel; the other tiles are not touched."""
+    from repro.kernels.tile_mem_store import tile_mem_store
+    return tile_mem_store(mem, addr, newval, write, interpret=False)
+
+
+def _mem_store(mem: jax.Array, addr: jax.Array, newval: jax.Array,
+               write: jax.Array) -> jax.Array:
+    """The step's write of a memory stored in rows: the in-place kernel
+    where the program is lowered for a TPU, the XLA scatter elsewhere
+    (:func:`mem_access_form`).  The two leave the same memory."""
+    return lax.platform_dependent(mem, addr, newval, write,
+                                  tpu=_mem_store_kernel,
+                                  default=_mem_scatter)
 
 
 def mem_shape(cfg: SimConfig) -> Tuple[int, ...]:
@@ -691,8 +737,10 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
     The endpoint's memory read and write are one-hot selects over
     ``mem_words`` where :func:`_mem_onehot` says so (always in the
     kernel; on the fused path up to :data:`MEM_ONEHOT_MAX_WORDS` words),
-    else one word gathered and one scattered per tile, from a memory
-    stored in rows (:func:`mem_shape`).  The
+    else, from a memory stored in rows (:func:`mem_shape`), one word
+    gathered per tile and the storing tiles' words written in place by
+    the ``tile_mem_store`` kernel on a TPU, or one word scattered per
+    tile elsewhere (:func:`_mem_store`, :func:`mem_access_form`).  The
     program-entry fetch is one where :func:`_entry_fetch_onehot` says so
     (always in the kernel; on the fused path up to
     :data:`ENTRY_ONEHOT_MAX_LP` entries).  On a TPU an element-wise
@@ -811,14 +859,14 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
                 cur = jnp.where(addr_oh, st.mem, 0).sum(
                     tuple(range(2, st.mem.ndim)))
             else:
-                at = (ys, xs, addr // MEM_ROW, addr % MEM_ROW)
-                cur = st.mem.at[at].get(mode="promise_in_bounds")
+                cur = st.mem.at[_mem_at(addr)].get(mode="promise_in_bounds")
         is_store = can & (req_op == OP_STORE)
         is_load = can & (req_op == OP_LOAD)
         is_cas = can & (req_op == OP_CAS)
         cas_hit = is_cas & (cur == req[_FI["cmp"]])
         # the current word wherever nothing is stored
-        newval = jnp.where(is_store | cas_hit, req[_FI["data"]], cur)
+        write = is_store | cas_hit
+        newval = jnp.where(write, req[_FI["data"]], cur)
         with jax.named_scope("mem"):
             if mem_onehot:
                 can_w = _expand(can, -1, kernel_safe)
@@ -828,9 +876,7 @@ def _step_core(cfg: SimConfig, prog: Program, st: SimState, *,
                                 newval[(...,) + (None,) * (1 + rows)],
                                 st.mem)
             else:
-                mem = st.mem.at[at].set(
-                    newval, indices_are_sorted=True, unique_indices=True,
-                    mode="promise_in_bounds")
+                mem = _mem_store(st.mem, addr, newval, write)
         ep_in = _fifo_pop(st.ep_in, _expand(can, -1, kernel_safe),
                           jnp.asarray(cfg.ep_fifo, I32))
         rdata = jnp.where(is_load | is_cas, cur, 0)

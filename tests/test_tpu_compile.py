@@ -15,14 +15,17 @@ import os
 import re
 
 import jax
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.netsim import LAT_BINS
 from repro.kernels.router_step import VMEM_LIMIT_BYTES, router_step_call
 from repro.netsim_jax.measure import SweepKey, batch_stats_fn
 from repro.netsim_jax.sim import (I32, PROG_FIELDS, Program, SimConfig,
                                   init_state, run_until_drained_traced,
                                   simulate)
+from repro.sim_service.streaming import _block_jit
 
 
 @pytest.fixture(scope="module")
@@ -108,13 +111,22 @@ WHOLE_MEMORY = re.compile(
     r"(?:select|iota|compare|copy|reshape)\(")
 
 
+def _scatter_sizes(text: str):
+    """Element counts of the int32 scatters in a compiled program."""
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"= s32\[([0-9,]*)\]\{[^}]*\} scatter\(",
+                                   text)]
+
+
 def test_deployment_memory_bucket_compiles_without_onehot(
         one_chip, no_persistent_cache):
     """The Epiphany-V sweep's bucket, one chip's chunk of 16 points at
     32x32 with 16,384 words per tile and 121 entries per tile, compiles,
-    and reads and writes one word per tile: no one-hot over the whole
-    memory and no copy of it into another layout.  The memory digest
-    reads the whole memory once a point, as uint32."""
+    and reads one word per tile and writes the storing tiles' words in
+    place: the ``tile_mem_store`` kernel, no scatter over the memory (the
+    one scatter left is the latency histogram's), no one-hot over the
+    whole memory and no copy of it into another layout.  The memory
+    digest reads the whole memory once a point, as uint32."""
     cfg = SimConfig(nx=32, ny=32, router_fifo=4, max_out_credits=32,
                     mem_words=16384)
     key = SweepKey(cfg, warmup=200, measure=400, drain=400)
@@ -122,8 +134,36 @@ def test_deployment_memory_bucket_compiles_without_onehot(
     knob = jax.ShapeDtypeStruct((16,), I32, sharding=one_chip)
     text = jax.jit(batch_stats_fn(key, digest=True)).lower(
         progs, knob, knob).compile().as_text()
-    assert "scatter(" in text
+    assert "tile_mem_store" in text
+    assert max(_scatter_sizes(text), default=0) <= 16 * LAT_BINS
     assert WHOLE_MEMORY.search(text) is None
+
+
+@pytest.mark.parametrize("program", ["drain", "service_block"])
+def test_celerity_programs_compile_without_store_kernel(
+        one_chip, no_persistent_cache, program):
+    """At the Celerity cells' 64 words per tile the memory is read and
+    written by one-hot select: the drain program (16x32, 128 entries per
+    tile) and the service's block (a batch of 8) hold neither the store
+    kernel nor a scatter over the memory, only the latency histogram's."""
+    cfg = _cfg(16, 32)
+    state = jax.eval_shape(lambda: init_state(cfg))
+    if program == "drain":
+        batch = 1
+        lowered = run_until_drained_traced.lower(
+            cfg, _on(one_chip, _program(cfg, 128)), _on(one_chip, state),
+            100_000, 1, "fused", 1)
+    else:
+        batch = 8
+        states = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((batch,) + x.shape, x.dtype),
+            state)
+        lowered = _block_jit(SweepKey(cfg, 200, 400, 400), 100).lower(
+            _on(one_chip, _program(cfg, 64, batch=(batch,))),
+            _on(one_chip, states))
+    text = lowered.compile().as_text()
+    assert "tile_mem_store" not in text
+    assert max(_scatter_sizes(text), default=0) <= batch * LAT_BINS
 
 
 @pytest.mark.parametrize("n", [8, 16])
